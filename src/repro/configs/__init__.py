@@ -1,7 +1,9 @@
 """Architecture registry.
 
 ``get_config(arch_id)`` returns the full (production) config; ``get_reduced``
-returns the CPU smoke-test variant of the same family.
+returns the CPU smoke-test variant of the same family; ``get_chip_share``
+returns one chip's share of the config's stated deployment, for the
+families that have one.
 """
 from __future__ import annotations
 
@@ -50,6 +52,15 @@ def get_reduced(arch_id: str) -> ModelConfig:
     if arch_id not in _MODULES:
         raise KeyError(f"unknown arch {arch_id!r}; choose from {sorted(_MODULES)}")
     return _MODULES[arch_id].reduced()
+
+
+def get_chip_share(arch_id: str) -> ModelConfig:
+    mod = _MODULES.get(arch_id)
+    if mod is None or not hasattr(mod, "chip_share"):
+        have = sorted(k for k, m in _MODULES.items()
+                      if hasattr(m, "chip_share"))
+        raise KeyError(f"no one-chip share for {arch_id!r}; have {have}")
+    return mod.chip_share()
 
 
 def all_configs() -> Dict[str, ModelConfig]:

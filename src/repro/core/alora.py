@@ -118,11 +118,13 @@ def pad_adapter_rank(weights: Params, target_rank: int) -> Params:
 
     The **zero-block invariant**: for every A/B pair the delta is
     ``x @ A @ B``; appending zero *columns* to A (axis -1) and matching
-    zero *rows* to B (axis -2) leaves the product bit-identical —
-    ``x @ [A|0] @ [B;0] == x @ A @ B``.  This is what lets heterogeneous
-    ranks share one bucketed slot shape in the device-resident adapter
-    pool without perturbing aLoRA semantics (pre-activation tokens still
-    see an exact zero delta through adapter index 0).
+    zero *rows* to B (axis -2) adds only exact zeros, so
+    ``x @ [A|0] @ [B;0] == x @ A @ B`` up to float rounding (XLA may
+    order a longer contraction differently, so it is not bit-identical).
+    This is what lets heterogeneous ranks share one bucketed slot shape
+    in the device-resident adapter pool without changing aLoRA semantics:
+    pre-activation tokens still see an exactly zero delta through
+    adapter index 0.
     """
     r = adapter_rank_of(weights)
     if r == target_rank:

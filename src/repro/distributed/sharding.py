@@ -104,21 +104,6 @@ def fit_spec(spec: P, shape: Tuple[int, ...], mesh: MeshLike) -> P:
     return P(*out)
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions: the new top-level API takes
-    ``check_vma``; 0.4.x only has ``jax.experimental.shard_map`` whose
-    equivalent knob is ``check_rep``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as sm_old
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-
-
 def _leaf_spec(path: Tuple[str, ...], shape: Tuple[int, ...],
                cfg: ModelConfig, model: str, n_lead: int) -> P:
     """Spec for one parameter leaf.  ``n_lead`` = stacking dims (layer

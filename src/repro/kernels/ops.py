@@ -1,10 +1,10 @@
-"""jit'd public wrappers for the Pallas kernels: shape padding, dtype
-handling, and CPU fallback (interpret mode) so the same call sites work
-in tests (CPU) and production (TPU)."""
+"""jit'd public wrappers for the Pallas kernels: shape padding and dtype
+handling.  ``interpret`` is an explicit argument of every wrapper: the
+caller says whether the kernel runs in interpret mode (tests and CPU
+benchmarks pass ``True``) or compiles for the TPU (``False``)."""
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -15,18 +15,12 @@ from repro.kernels.paged_attention import (paged_attention,
                                            ragged_paged_attention)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("t_block", "o_block", "interpret"))
 def alora_qkv_op(x: jax.Array, w: jax.Array, a_stack: jax.Array,
                  b_stack: jax.Array, adapter_idx: jax.Array, *,
                  t_block: int = 256, o_block: int = 256,
-                 interpret: Optional[bool] = None) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """Padded/jitted fused aLoRA projection.  x: (T, d) -> (T, out)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     T, d = x.shape
     out = w.shape[1]
     tb = min(t_block, max(T, 8))
@@ -46,10 +40,8 @@ def alora_qkv_op(x: jax.Array, w: jax.Array, a_stack: jax.Array,
 def paged_attention_op(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                        block_tables: jax.Array, lengths: jax.Array, *,
                        window: int = 0,
-                       interpret: Optional[bool] = None) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """Paged GQA decode attention.  q: (B, H, hd) -> (B, H, hd)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     return paged_attention(q, k_pool, v_pool, block_tables, lengths,
                            window=window, interpret=interpret)
 
@@ -59,11 +51,9 @@ def ragged_paged_attention_op(q: jax.Array, k_pool: jax.Array,
                               v_pool: jax.Array, block_tables: jax.Array,
                               req_rows: jax.Array, q_lens: jax.Array, *,
                               window: int = 0,
-                              interpret: Optional[bool] = None
+                              interpret: bool
                               ) -> jax.Array:
     """Mixed-batch ragged paged attention.  q: (T, H, hd) -> (T, H, hd)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     return ragged_paged_attention(q, k_pool, v_pool, block_tables,
                                   req_rows, q_lens, window=window,
                                   interpret=interpret)
@@ -72,12 +62,10 @@ def ragged_paged_attention_op(q: jax.Array, k_pool: jax.Array,
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_chunk_scan_op(x: jax.Array, B: jax.Array, C: jax.Array,
                       dA: jax.Array, dt: jax.Array, *, chunk: int = 128,
-                      interpret: Optional[bool] = None):
+                      interpret: bool):
     """Padded/jitted SSD chunk scan.  Pads S to a chunk multiple with
     dt=0 (decay 1, zero input ⇒ state invariant)."""
     from repro.kernels.ssd_chunk import ssd_chunk_scan
-    if interpret is None:
-        interpret = not _on_tpu()
     Bt, S, H, P = x.shape
     ch = min(chunk, max(S, 8))
     Sp = ((S + ch - 1) // ch) * ch
@@ -97,15 +85,13 @@ def ragged_ssd_scan_op(x: jax.Array, B: jax.Array, C: jax.Array,
                        dA: jax.Array, dt: jax.Array, seg_ids: jax.Array,
                        seg_starts: jax.Array, slot_rows: jax.Array,
                        init_states: jax.Array, *, chunk: int = 64,
-                       interpret: Optional[bool] = None):
+                       interpret: bool):
     """Padded/jitted ragged SSD scan over a packed token axis.
 
     Pads T to a chunk multiple with dA=dt=0 (decay 1, zero input ⇒ carry
     invariant) and seg_starts=0 (padding continues the trailing segment,
     whose emitted rows the caller never gathers)."""
     from repro.kernels.ssd_chunk import ragged_ssd_chunk_scan
-    if interpret is None:
-        interpret = not _on_tpu()
     T = x.shape[0]
     ch = min(chunk, max(T, 8))
     Tp = ((T + ch - 1) // ch) * ch
@@ -128,12 +114,10 @@ def ragged_ssd_scan_op(x: jax.Array, B: jax.Array, C: jax.Array,
 def ragged_lora_op(x: jax.Array, a_stack: jax.Array, b_stack: jax.Array,
                    adapter_idx: jax.Array, active_slots: jax.Array, *,
                    t_block: int = 256, o_block: int = 256,
-                   interpret: Optional[bool] = None) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """Padded/jitted SGMV-style grouped-LoRA delta over per-token slot
     indices.  x: (T, d) -> (T, out)."""
     from repro.kernels.ragged_lora import ragged_grouped_lora_padded
-    if interpret is None:
-        interpret = not _on_tpu()
     return ragged_grouped_lora_padded(x, a_stack, b_stack, adapter_idx,
                                       active_slots, t_block=t_block,
                                       o_block=o_block, interpret=interpret)

@@ -66,12 +66,12 @@ def ragged_grouped_lora_ref(x: jax.Array, a_stack: jax.Array,
 def _ragged_lora_kernel(slots_ref, idx_ref, x_ref, a_ref, b_ref, o_ref, *,
                         n_active: int):
     x = x_ref[...]                                     # (Tt, d)
-    idx = idx_ref[...]                                 # (Tt,)
+    idx = idx_ref[...]                                 # (Tt, 1)
     acc = jnp.zeros(x.shape[:1] + o_ref.shape[1:], jnp.float32)
     for i in range(n_active):                          # static unroll
         s = slots_ref[i]                               # dynamic slot id
-        sel = (idx == s) & (s > 0)
-        xm = jnp.where(sel[:, None], x, jnp.zeros_like(x))
+        sel = (idx == s) & (s > 0)                     # (Tt, 1)
+        xm = jnp.where(sel, x, jnp.zeros_like(x))
         xa = jnp.dot(xm, a_ref[s],
                      preferred_element_type=jnp.float32)    # (Tt, r)
         acc = acc + jnp.dot(xa.astype(x.dtype), b_ref[s],
@@ -101,7 +101,10 @@ def ragged_grouped_lora(x: jax.Array, a_stack: jax.Array,
             num_scalar_prefetch=1,                     # active_slots
             grid=grid,
             in_specs=[
-                pl.BlockSpec((t_block,), lambda i, j, slots: (i,)),  # idx
+                # per-token slot ids as a (T, 1) column: a 2-D block
+                # Mosaic lays out directly (a 1-D bool mask would need a
+                # vector<T x i1> -> <T x 1 x i1> reshape it refuses)
+                pl.BlockSpec((t_block, 1), lambda i, j, slots: (i, 0)),
                 pl.BlockSpec((t_block, d), lambda i, j, slots: (i, 0)),
                 pl.BlockSpec((n, d, r), lambda i, j, slots: (0, 0, 0)),
                 pl.BlockSpec((n, r, o_block),
@@ -112,7 +115,7 @@ def ragged_grouped_lora(x: jax.Array, a_stack: jax.Array,
         ),
         out_shape=jax.ShapeDtypeStruct((T, out), x.dtype),
         interpret=interpret,
-    )(active_slots, adapter_idx, x, a_stack, b_stack)
+    )(active_slots, adapter_idx.reshape(T, 1), x, a_stack, b_stack)
 
 
 def ragged_grouped_lora_padded(x: jax.Array, a_stack: jax.Array,

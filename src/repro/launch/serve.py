@@ -8,6 +8,12 @@ through the engine, and prints per-stage latency + cache-hit metrics.
   PYTHONPATH=src python -m repro.launch.serve --arch granite-3.2-8b \
       --requests 8 --prompt-len 128
 
+``--scale chip`` serves one chip's share of the architecture's stated
+deployment at its published widths (``configs.get_chip_share``; on a TPU
+v5e) with the KV pool sized by ``CHIP_ENGINE_CFG``, instead of the
+reduced CPU model.  Compiled programs persist in JAX's compile cache
+(``launch/compile_cache.py``).
+
 ``--replicas N`` scales the same workload out over N in-process engine
 replicas behind the cache-affinity router (``serving/router.py``) —
 each replica gets its own pools, prefix cache and adapter slots, and
@@ -30,9 +36,10 @@ import argparse
 import jax
 import numpy as np
 
-from repro.configs import get_reduced
+from repro.configs import get_chip_share, get_reduced
 from repro.core.alora import (PAPER_ALORA_RANK, PAPER_LORA_RANK,
                               AdapterSpec, init_adapter_weights)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.obs import prometheus_text, write_perfetto
 from repro.serving import Engine, EngineConfig, fmt_speedups, speedup_table
@@ -40,19 +47,31 @@ from repro.serving import pipelines as P
 from repro.serving.router import POLICIES, Router
 
 
+# One v5e chip (15.75 GiB usable) serving granite-3.2-8b's 20-layer share
+# (8.4 GB of bf16 weights): 2,048 blocks x 16 tokens of bf16 K/V over 20
+# layers is 2.5 GiB, and a 128-token step at a 2k context needs about
+# 1.4 GiB of temporaries on the jnp attention path.
+CHIP_ENGINE_CFG = EngineConfig(num_blocks=2048, max_running=8,
+                               max_batched_tokens=128)
+
+
+def build_adapters(cfg, kind: str, n_adapters: int):
+    """``[(spec, weights)]`` of ``n_adapters`` seeded adapters named
+    ``intrinsic{i}``: aLoRA (rank 32, invoked by tokens 3, 4, 5) or
+    vanilla LoRA (rank 8) as in the paper's setup."""
+    rank = PAPER_ALORA_RANK if kind == "alora" else PAPER_LORA_RANK
+    inv = tuple(range(3, 6)) if kind == "alora" else None
+    return [(AdapterSpec(f"intrinsic{i}", rank=rank, invocation_tokens=inv),
+             init_adapter_weights(jax.random.key(100 + i), cfg, rank))
+            for i in range(n_adapters)]
+
+
 def build_engine(cfg, params, kind: str, n_adapters: int = 1,
                  engine_cfg: EngineConfig = EngineConfig(),
                  replicas: int = 1, route: str = "affinity"):
     """One engine, or — with ``replicas > 1`` — a Router over N
     identically-built replicas (drop-in for the pipeline drivers)."""
-    rank = PAPER_ALORA_RANK if kind == "alora" else PAPER_LORA_RANK
-    adapters = []
-    for i in range(n_adapters):
-        inv = tuple(range(3, 6)) if kind == "alora" else None
-        spec = AdapterSpec(f"intrinsic{i}", rank=rank,
-                           invocation_tokens=inv)
-        w = init_adapter_weights(jax.random.key(100 + i), cfg, rank)
-        adapters.append((spec, w))
+    adapters = build_adapters(cfg, kind, n_adapters)
 
     def mk() -> Engine:
         return Engine(cfg, params, adapters=adapters,
@@ -74,6 +93,10 @@ def collect_tracers(eng):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3.2-8b")
+    ap.add_argument("--scale", choices=("reduced", "chip"),
+                    default="reduced",
+                    help="reduced: tiny float32 model (CPU); chip: one "
+                         "chip's share at published widths (TPU)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=128)
     ap.add_argument("--gen-len", type=int, default=32)
@@ -92,10 +115,14 @@ def main() -> None:
                          "Prometheus text exposition format")
     args = ap.parse_args()
 
-    cfg = get_reduced(args.arch)
+    enable_compile_cache()
+    if args.scale == "chip":
+        cfg, engine_cfg = get_chip_share(args.arch), CHIP_ENGINE_CFG
+    else:
+        cfg, engine_cfg = get_reduced(args.arch), EngineConfig()
     tier = f" x{args.replicas} replicas ({args.route})" \
         if args.replicas > 1 else ""
-    print(f"serving reduced {cfg.name} ({cfg.arch_type}){tier}")
+    print(f"serving {args.scale} {cfg.name} ({cfg.arch_type}){tier}")
     params = init_params(jax.random.key(0), cfg)
 
     results = {}
@@ -104,6 +131,7 @@ def main() -> None:
         # measures with cold caches but warm code
         for seed in (123, 0):
             eng = build_engine(cfg, params, kind, args.adapters,
+                               engine_cfg=engine_cfg,
                                replicas=args.replicas, route=args.route)
             names = [f"intrinsic{i}" for i in range(args.adapters)]
             res = P.base_adapter(

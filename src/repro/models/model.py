@@ -33,6 +33,7 @@ base-model tokens and pre-activation tokens of an aLoRA request).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
@@ -187,36 +188,70 @@ def _init_layer(key, cfg: ModelConfig, kind: str, dtype,
     return p
 
 
+@partial(jax.jit, donate_argnums=0)
+def _set_layer(stacked: Params, r, c, layer: Params) -> Params:
+    return jax.tree.map(lambda s, x: s.at[r, c].set(x), stacked, layer)
+
+
 def _stack_layers(key, cfg: ModelConfig, kind: str, repeats: int, count: int,
-                  dtype, cross: bool = False) -> Params:
+                  dtype, cross: bool = False, shardings=None) -> Params:
+    """(repeats, count, ...)-stacked layer params, written layer by layer
+    into preallocated leaves (donated, so updated in place): the device
+    holds one stacked copy plus one layer, never a per-layer list and its
+    stacked twin.  Layer ``i`` (row-major) is exactly
+    ``_init_layer(split(key, repeats*count)[i])``.  ``shardings`` (a tree
+    like the result) allocates the leaves sharded."""
     keys = jax.random.split(key, repeats * count)
-    ps = [_init_layer(k, cfg, kind, dtype, cross) for k in keys]
-    return jax.tree.map(
-        lambda *xs: jnp.stack(xs).reshape((repeats, count) + xs[0].shape), *ps)
+    init = partial(_init_layer, cfg=cfg, kind=kind, dtype=dtype, cross=cross)
+    shapes = jax.eval_shape(init, keys[0])
+    if shardings is None:
+        shardings = jax.tree.map(lambda _: None, shapes)
+    stacked = jax.tree.map(
+        lambda a, sh: jnp.zeros((repeats, count) + a.shape, a.dtype,
+                                device=sh), shapes, shardings)
+    for i in range(repeats * count):
+        stacked = _set_layer(stacked, i // count, i % count, init(keys[i]))
+    return stacked
 
 
-def init_params(key, cfg: ModelConfig) -> Params:
+def init_params(key, cfg: ModelConfig, shardings: Optional[Params] = None
+                ) -> Params:
+    """Seeded random parameters.  ``shardings`` (a ``NamedSharding``
+    tree like the result, e.g. from ``distributed.sharding``) places
+    every leaf on a mesh as it is built, so a model larger than one
+    device never has to exist on one."""
+    sh = shardings or {}
+
+    def put(x, s):
+        return x if s is None else jax.device_put(x, s)
+
     dtype = L.dtype_of(cfg)
     repeats, segs = period_segments(cfg)
     k_emb, k_blocks, k_enc = jax.random.split(key, 3)
     seg_keys = jax.random.split(k_blocks, len(segs))
     params: Params = {
-        "embed": L.init_embeddings(k_emb, cfg, dtype),
-        "final_norm": L.init_rmsnorm(cfg.d_model, dtype),
+        "embed": put(L.init_embeddings(k_emb, cfg, dtype), sh.get("embed")),
+        "final_norm": put(L.init_rmsnorm(cfg.d_model, dtype),
+                          sh.get("final_norm")),
         "blocks": {
             f"seg{i}": _stack_layers(seg_keys[i], cfg, kind, repeats, count,
                                      dtype,
                                      cross=cfg.is_encoder_decoder
-                                     and kind == ATTN)
+                                     and kind == ATTN,
+                                     shardings=sh.get("blocks", {}).get(
+                                         f"seg{i}"))
             for i, (kind, count) in enumerate(segs)
         },
     }
     if cfg.is_encoder_decoder:
         ek = jax.random.split(k_enc, 2)
+        esh = sh.get("encoder", {})
         params["encoder"] = {
             "blocks": _stack_layers(ek[0], cfg, ATTN, cfg.num_encoder_layers,
-                                    1, dtype, cross=False),
-            "final_norm": L.init_rmsnorm(cfg.d_model, dtype),
+                                    1, dtype, cross=False,
+                                    shardings=esh.get("blocks")),
+            "final_norm": put(L.init_rmsnorm(cfg.d_model, dtype),
+                              esh.get("final_norm")),
         }
     return params
 
@@ -294,10 +329,9 @@ def _context_parallel_attention(cfg: ModelConfig, rt: Runtime, q, k, v,
     spec = P(b, m, None, None)
     # check_vma off: flash_attention's scan carries start as invariant
     # zeros, which the varying-axes checker rejects inside shard_map
-    from repro.distributed.sharding import shard_map
-    return shard_map(local, mesh=rt.mesh,
-                     in_specs=(spec, spec, spec),
-                     out_specs=spec, check_vma=False)(q, k, v)
+    return jax.shard_map(local, mesh=rt.mesh,
+                         in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def cross_attn_sublayer(lp: Params, cfg: ModelConfig, x: jax.Array,
@@ -678,7 +712,12 @@ def decode_step(params: Params, cfg: ModelConfig, token: jax.Array,
 
 def logits_for(params: Params, cfg: ModelConfig, hidden: jax.Array
                ) -> jax.Array:
-    return L.unembed(params["embed"], hidden, cfg.tie_embeddings)
+    """Logits over the real vocabulary.  The embedding has
+    ``padded_vocab`` rows so it shards evenly; the rows past
+    ``vocab_size`` are not tokens, so they are sliced off here and no
+    sampler can pick them."""
+    logits = L.unembed(params["embed"], hidden, cfg.tie_embeddings)
+    return logits[..., :cfg.vocab_size]
 
 
 def prefill_to_decode_caches(cfg: ModelConfig, prefill_caches: Params,

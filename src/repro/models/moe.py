@@ -181,8 +181,7 @@ def moe_expert_parallel(p: Params, cfg: ModelConfig, x: jax.Array, *,
     w_stack = {k: v for k, v in p.items() if k != "router"}
     bspec = P(batch_axes, None, None)
     wspec = jax.tree.map(lambda _: P(model_axis), w_stack)
-    from repro.distributed.sharding import shard_map
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(bspec, P(), wspec),
         out_specs=(bspec, P()),

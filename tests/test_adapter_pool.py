@@ -77,14 +77,19 @@ class TestRegistry:
 
     def test_rank_padding_is_exact(self, setup):
         """x @ [A|0] @ [B;0] == x @ A @ B — the zero-block invariant the
-        bucketed slot shapes rely on."""
+        bucketed slot shapes rely on.  The padded product equals the
+        unpadded one up to float rounding: the zero blocks add exact
+        zeros, but XLA may split or reorder a contraction of length 32
+        differently from one of length 8, so bit-equality is not
+        promised.  What must stay exact is that index-0 tokens (base and
+        pre-activation) get a zero delta."""
         cfg, _ = setup
         w = mk_weights(cfg, 3, rank=8)
         padded = pad_adapter_rank(w, 32)
         seg, seg_p = w["seg0"], padded["seg0"]
         assert seg_p["aq"].shape[-1] == 32 and seg_p["bq"].shape[-2] == 32
         x = jax.random.normal(jax.random.key(9), (6, cfg.d_model))
-        idx = np.ones(6, np.int32)
+        idx = np.array([1, 1, 1, 0, 1, 0], np.int32)
         for a_k, b_k in (("aq", "bq"), ("ak", "bk"), ("av", "bv")):
             d0 = lora_delta(x, jax.numpy.stack(
                 [jax.numpy.zeros_like(seg[a_k][0, 0]), seg[a_k][0, 0]]),
@@ -95,7 +100,12 @@ class TestRegistry:
                  seg_p[a_k][0, 0]]),
                 jax.numpy.stack([jax.numpy.zeros_like(seg_p[b_k][0, 0]),
                                  seg_p[b_k][0, 0]]), idx)
-            np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
+            d0, d1 = np.asarray(d0), np.asarray(d1)
+            # float32 products of O(1) terms: a reordered sum of 8 of
+            # them differs by a few ulps
+            np.testing.assert_allclose(d1, d0, rtol=1e-5, atol=1e-6)
+            assert np.abs(d0[idx == 1]).max() > 0
+            np.testing.assert_array_equal(d1[idx == 0], 0.0)
 
     def test_stack_adapters_mixes_ranks(self, setup):
         """The old `equal-rank` assertion is gone: heterogeneous ranks

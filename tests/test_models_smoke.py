@@ -123,3 +123,52 @@ def test_sliding_window_ring_buffer():
     got, _ = decode_step(params, cfg, toks[:, S:], dc, S)
     np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("arch", ["granite-3.2-8b", "zamba2-2.7b",
+                                  "whisper-large-v3"])
+def test_init_params_matches_per_layer_stacking(arch):
+    """``init_params`` writes each layer into preallocated stacked leaves;
+    the values must equal building every layer on its own and stacking
+    the list, for the same key (dense, hybrid and encoder-decoder
+    stacks)."""
+    from repro.models import layers as L
+    from repro.models.model import ATTN, _init_layer, period_segments
+    cfg = get_reduced(arch)
+    key = jax.random.key(3)
+
+    def stack(k, kind, repeats, count, cross):
+        ps = [_init_layer(kk, cfg, kind, L.dtype_of(cfg), cross)
+              for kk in jax.random.split(k, repeats * count)]
+        return jax.tree.map(lambda *xs: jnp.stack(xs).reshape(
+            (repeats, count) + xs[0].shape), *ps)
+
+    repeats, segs = period_segments(cfg)
+    _, k_blocks, k_enc = jax.random.split(key, 3)
+    seg_keys = jax.random.split(k_blocks, len(segs))
+    got = init_params(key, cfg)
+    for i, (kind, count) in enumerate(segs):
+        want = stack(seg_keys[i], kind, repeats, count,
+                     cfg.is_encoder_decoder and kind == ATTN)
+        jax.tree.map(np.testing.assert_array_equal,
+                     got["blocks"][f"seg{i}"], want)
+    if cfg.is_encoder_decoder:
+        want = stack(jax.random.split(k_enc, 2)[0], ATTN,
+                     cfg.num_encoder_layers, 1, False)
+        jax.tree.map(np.testing.assert_array_equal,
+                     got["encoder"]["blocks"], want)
+
+
+def test_init_params_with_shardings_places_each_leaf():
+    from repro.distributed import sharding as shd
+    from repro.launch.mesh import make_host_mesh
+    from repro.models import param_specs
+    cfg = get_reduced("granite-3.2-8b")
+    mesh = make_host_mesh()
+    named = shd.to_named(shd.param_specs_tree(cfg, param_specs(cfg),
+                                              mesh=mesh), mesh)
+    got = init_params(jax.random.key(0), cfg, named)
+    want = init_params(jax.random.key(0), cfg)
+    jax.tree.map(np.testing.assert_array_equal, got, want)
+    for a, s in zip(jax.tree.leaves(got), jax.tree.leaves(named)):
+        assert a.sharding.is_equivalent_to(s, a.ndim)
