@@ -13,7 +13,8 @@ path, ``launch/serve.py``'s ``build_engine`` -> ``Engine`` ->
 
   1. kernels: the ragged paged-attention and grouped-LoRA Pallas
      kernels compile for the chip (``tpu_custom_call``) and match their
-     jnp references at granite widths;
+     jnp references at granite widths, and ragged SSD at mamba2-2.7b
+     widths;
   2. serve: 4 pipelines of ~2k-token prompts; every request finishes
      with valid token ids, the adapter stage reuses the base stage's
      KV blocks (cache hit share > 0), and the drained engine holds no
@@ -162,6 +163,40 @@ def check_kernels(cfg) -> None:
             # output too (2^-9 relative each)
             close(f"grouped LoRA T={T} d={d} out={out} rank={r}",
                   c(x, a, b, idx, act), want, 2e-2)
+
+    # ragged SSD at mamba2-2.7b widths: 4 decode rows and two prefill
+    # segments of 60 and 64 tokens in one 128-token packed batch
+    from repro.configs import get_config
+    from repro.kernels.ops import ragged_ssd_scan_op
+    from repro.kernels.ref import ragged_ssd_scan_ref
+    m = get_config("mamba2-2.7b")
+    H, P, N = m.ssm.expand * m.d_model // m.ssm.head_dim, m.ssm.head_dim, \
+        m.ssm.state_dim
+    lens, n_slots = [1, 1, 1, 1, 60, 64], 9
+    T = sum(lens)
+    seg = np.repeat(np.arange(len(lens)), lens)
+    starts = np.concatenate([[True], seg[1:] != seg[:-1]])
+    slots = seg + 1
+    ks = jax.random.split(ks[0], 6)
+    dt = jax.random.uniform(ks[0], (T, H), minval=1e-3, maxval=0.1)
+    args = (jax.random.normal(ks[1], (T, H, P)),
+            jax.random.normal(ks[2], (T, H, N)),
+            jax.random.normal(ks[3], (T, H, N)),
+            -dt * jnp.arange(1, H + 1, dtype=jnp.float32), dt,
+            jnp.asarray(seg, jnp.int32), jnp.asarray(starts),
+            jnp.asarray(slots, jnp.int32),
+            jax.random.normal(ks[4], (n_slots, H, N, P)) * 0.1)
+    c = compiled(lambda *a: ragged_ssd_scan_op(*a, chunk=16,
+                                               interpret=False), *args)
+    y, st = c(*args)
+    with jax.default_matmul_precision("highest"):
+        y_r, st_r = jax.jit(ragged_ssd_scan_ref)(*args[:5], *args[6:])
+    # float32 throughout; the kernel sums each state as one matmul over
+    # its chunk instead of token by token, on an MXU that may round f32
+    # operands to bf16 passes (2^-9 relative)
+    close(f"ragged SSD T={T} H={H} P={P} N={N} chunk=16 (y)", y, y_r,
+          2e-2)
+    close("ragged SSD (post-token states)", st, st_r, 2e-2)
 
 
 # ---------------------------------------------------------------------------

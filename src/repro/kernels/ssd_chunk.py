@@ -117,62 +117,50 @@ def ssd_chunk_scan(x: jax.Array, B: jax.Array, C: jax.Array,
 # ---------------------------------------------------------------------------
 # Ragged (packed-axis) variant — the mixed serving step's SSD scan
 # ---------------------------------------------------------------------------
-def _ragged_ssd_kernel(x_ref, b_ref, c_ref, da_ref, dt_ref, sid_ref,
-                       start_ref, slot_ref, init_ref, y_ref, st_ref,
-                       state_scr, *, Q: int):
-    """Segment-boundary-aware SSD chunk over the PACKED token axis.
+def _ragged_ssd_kernel(has_ref, slot_ref, x_ref, bt_ref, c_ref, sw_ref,
+                       decay_ref, init_ref, y_ref, st_ref, state_scr, *,
+                       Q: int):
+    """One (head, chunk) of the ragged SSD scan over the PACKED token
+    axis; a chunk may span several request segments.
 
-    One chunk may span several request segments: the decay matrix is
-    additionally masked to same-segment pairs, and each token's entry
-    state is either the scratch carry (segment spans the chunk boundary)
-    or a row of the live-state pool gathered at the segment's in-chunk
-    start.  Emits the post-token state at every position (the interpret-
-    mode contract; a production TPU kernel would emit only block-boundary
-    rows and fold y into the three-matmul form of ``_ssd_kernel``).
+    Per-chunk quantities arrive precomputed (``ragged_ssd_chunk_scan``):
+    ``sw`` (Q, Q) the same-segment causal decay weights
+    ``e^{csum_q - csum_k}·dt_k``, ``decay`` (Q, P) the decay from each
+    token's entry state (repeated along P: Mosaic cannot broadcast a
+    (1, 1) value over both axes of the state), and per token (scalar
+    prefetch) whether its segment starts inside the chunk and which
+    live-state slot it starts from.  Post-token state of token q:
+
+      state_q = Bᵀ·diag(sw[q])·x + decay_q · entry_q
+
+    with entry_q the pool row of its segment's start slot, or the state
+    carried in from the previous chunk.  Emits every token's state (the
+    caller gathers segment-final and block-boundary rows).
     """
-    c_idx = pl.program_id(1)
+    c = pl.program_id(1)
 
-    @pl.when(c_idx == 0)
+    @pl.when(c == 0)
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[:, 0].astype(jnp.float32)               # (Q, P)
-    B = b_ref[:, 0].astype(jnp.float32)               # (Q, N)
-    C = c_ref[:, 0].astype(jnp.float32)               # (Q, N)
-    dA = da_ref[:, 0]                                 # (Q,)
-    dt = dt_ref[:, 0]                                 # (Q,)
-    sid = sid_ref[...]                                # (Q,) int32
-    is_start = start_ref[...]                         # (Q,) int32
-    slots = slot_ref[...]                             # (Q,) int32
-    init_states = init_ref[:, 0].astype(jnp.float32)  # (S, N, P)
-    N, P = state_scr.shape
-
-    csum = jnp.cumsum(dA)                             # (Q,)
-    qi = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-    ki = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    same = sid[:, None] == sid[None, :]
-    # intra-chunk state contributions: SW[q,k] = e^{csum_q - csum_k}·dt_k
-    # over same-segment causal pairs, applied to B_k ⊗ x_k (one Q×Q MXU
-    # matmul over the flattened (N·P) state)
-    SW = jnp.where((qi >= ki) & same,
-                   jnp.exp(csum[:, None] - csum[None, :]), 0.0) * dt[None, :]
-    Bx = (B[:, :, None] * x[:, None, :]).reshape(Q, N * P)
-    states = jnp.dot(SW, Bx,
-                     preferred_element_type=jnp.float32).reshape(Q, N, P)
-    # entry states: scratch carry, or the pool row gathered at the most
-    # recent in-chunk segment start
-    tok = jax.lax.broadcasted_iota(jnp.int32, (Q,), 0)
-    run_start = jax.lax.cummax(jnp.where(is_start > 0, tok, -1))
-    has_start = run_start >= 0
-    rs = jnp.maximum(run_start, 0)
-    e0 = jnp.where(has_start, csum[rs] - dA[rs], 0.0)
-    entry = jnp.where(has_start[:, None, None],
-                      init_states[slots[rs]], state_scr[...])
-    states = states + jnp.exp(csum - e0)[:, None, None] * entry
-    y = jnp.einsum("qn,qnp->qp", C, states)
-    state_scr[...] = states[Q - 1]
-    y_ref[:, 0] = y.astype(y_ref.dtype)
-    st_ref[:, 0] = states.astype(st_ref.dtype)
+    x = x_ref[0].astype(jnp.float32)                  # (Q, P)
+    bt = bt_ref[0, 0]                                 # (N, Q)
+    cm = c_ref[0]                                     # (Q, N)
+    sw = sw_ref[0, 0]                                 # (Q, Q)
+    decay = decay_ref[0, 0]                           # (Q, P)
+    carry = state_scr[...]
+    st = carry
+    for q in range(Q):                                # static unroll
+        t = c * Q + q
+        entry = jnp.where(has_ref[t] > 0, init_ref[slot_ref[t], 0], carry)
+        st = jnp.dot(bt * sw[q:q + 1, :], x,
+                     preferred_element_type=jnp.float32) \
+            + decay[q:q + 1, :] * entry               # (N, P)
+        st_ref[q, 0] = st
+        y_ref[0, q:q + 1, :] = jnp.dot(
+            cm[q:q + 1, :], st,
+            preferred_element_type=jnp.float32).astype(y_ref.dtype)
+    state_scr[...] = st
 
 
 def ragged_ssd_chunk_scan(x: jax.Array, B: jax.Array, C: jax.Array,
@@ -187,39 +175,70 @@ def ragged_ssd_chunk_scan(x: jax.Array, B: jax.Array, C: jax.Array,
     T % chunk == 0 (``repro.kernels.ops.ragged_ssd_scan_op`` auto-pads).
     Returns (y (T,H,P), states (T,H,N,P) fp32 — post-token states).
     Matches ``repro.kernels.ref.ragged_ssd_scan_ref``.
+
+    Layout: the kernel's blocks end in whole dims or (Q, ·) tiles, as
+    Mosaic requires — x, C and y head-major (H, T, ·); B per chunk and
+    transposed (H, nc, N, Q); states and init token-major with (N, P)
+    last.  The (Q, Q) segment masks, cumulative decays and entry slots
+    are computed here with plain jnp, once per call.
     """
     T, H, P = x.shape
     N = B.shape[-1]
     S = init_states.shape[0]
-    assert T % chunk == 0, (T, chunk)
-    nc = T // chunk
-    grid = (H, nc)                                    # chunk innermost
+    Q = chunk
+    assert T % Q == 0, (T, Q)
+    nc = T // Q
+    f32 = jnp.float32
 
-    kernel = functools.partial(_ragged_ssd_kernel, Q=chunk)
+    dA_c = dA.T.reshape(H, nc, Q)
+    csum = jnp.cumsum(dA_c, axis=-1)                   # (H, nc, Q)
+    tok = jnp.arange(Q)
+    # most recent in-chunk segment start at or before each token
+    run_start = jax.lax.cummax(
+        jnp.where(seg_starts.reshape(nc, Q) > 0, tok, -1), axis=1)
+    has = run_start >= 0
+    rs = jnp.maximum(run_start, 0)                     # (nc, Q)
+    at_rs = lambda a: jnp.take_along_axis(a, jnp.broadcast_to(rs, a.shape),
+                                          axis=-1)
+    e0 = jnp.where(has, at_rs(csum) - at_rs(dA_c), 0.0)
+    decay = jnp.broadcast_to(jnp.exp(csum - e0)[..., None],
+                             (H, nc, Q, P))
+    sid = seg_ids.reshape(nc, Q)
+    mask = (tok[:, None] >= tok[None, :]) & \
+        (sid[:, :, None] == sid[:, None, :])           # (nc, Q, Q)
+    sw = jnp.where(mask, jnp.exp(csum[..., :, None] - csum[..., None, :]),
+                   0.0) * dt.T.reshape(H, nc, 1, Q)    # (H, nc, Q, Q)
+    entry_slot = jnp.take_along_axis(slot_rows.reshape(nc, Q), rs, axis=1)
+
     y, st = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((chunk, 1, P), lambda h, c: (c, h, 0)),   # x
-            pl.BlockSpec((chunk, 1, N), lambda h, c: (c, h, 0)),   # B
-            pl.BlockSpec((chunk, 1, N), lambda h, c: (c, h, 0)),   # C
-            pl.BlockSpec((chunk, 1), lambda h, c: (c, h)),         # dA
-            pl.BlockSpec((chunk, 1), lambda h, c: (c, h)),         # dt
-            pl.BlockSpec((chunk,), lambda h, c: (c,)),             # seg_ids
-            pl.BlockSpec((chunk,), lambda h, c: (c,)),             # starts
-            pl.BlockSpec((chunk,), lambda h, c: (c,)),             # slots
-            pl.BlockSpec((S, 1, N, P), lambda h, c: (0, h, 0, 0)),  # init
-        ],
-        out_specs=[
-            pl.BlockSpec((chunk, 1, P), lambda h, c: (c, h, 0)),   # y
-            pl.BlockSpec((chunk, 1, N, P),
-                         lambda h, c: (c, h, 0, 0)),               # states
-        ],
+        functools.partial(_ragged_ssd_kernel, Q=Q),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(H, nc),                              # chunk innermost
+            in_specs=[
+                pl.BlockSpec((1, Q, P), lambda h, c, *_: (h, c, 0)),
+                pl.BlockSpec((1, 1, N, Q), lambda h, c, *_: (h, c, 0, 0)),
+                pl.BlockSpec((1, Q, N), lambda h, c, *_: (h, c, 0)),
+                pl.BlockSpec((1, 1, Q, Q), lambda h, c, *_: (h, c, 0, 0)),
+                pl.BlockSpec((1, 1, Q, P), lambda h, c, *_: (h, c, 0, 0)),
+                pl.BlockSpec((S, 1, N, P), lambda h, c, *_: (0, h, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, Q, P), lambda h, c, *_: (h, c, 0)),
+                pl.BlockSpec((Q, 1, N, P), lambda h, c, *_: (c, h, 0, 0)),
+            ],
+            scratch_shapes=[pltpu.VMEM((N, P), f32)],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct((T, H, P), x.dtype),
-            jax.ShapeDtypeStruct((T, H, N, P), jnp.float32),
+            jax.ShapeDtypeStruct((H, T, P), x.dtype),
+            jax.ShapeDtypeStruct((T, H, N, P), f32),
         ],
-        scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
-    )(x, B, C, dA, dt, seg_ids, seg_starts, slot_rows, init_states)
-    return y, st
+    )(has.reshape(T).astype(jnp.int32),
+      entry_slot.reshape(T).astype(jnp.int32),
+      x.transpose(1, 0, 2),
+      B.astype(f32).transpose(1, 2, 0).reshape(H, N, nc, Q)
+      .transpose(0, 2, 1, 3),
+      C.astype(f32).transpose(1, 0, 2), sw, decay,
+      init_states.astype(f32))
+    return y.transpose(1, 0, 2), st

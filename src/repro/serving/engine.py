@@ -100,8 +100,6 @@ class EngineConfig:
     # everywhere) | "pallas" (TPU kernel) | "pallas_interpret" (tests)
     mixed_attn_impl: str = "ref"
     # ragged-SSD impl for the mixed step, same choices as above
-    # ("pallas" is refused at construction on a TPU: Mosaic cannot
-    # compile the kernel's blocks yet)
     mixed_ssd_impl: str = "ref"
     # grouped-LoRA delta for the mixed step: "ref" (ragged jnp over the
     # step's active slots) | "pallas"/"pallas_interpret" (SGMV kernel) |

@@ -594,13 +594,6 @@ class ModelRunner:
         SSM pools on their head/channel dims, and per-step metadata is
         replicated.  ``None`` keeps the single-device default path
         byte-identical to before."""
-        if rcfg.mixed_ssd_impl == "pallas" \
-                and jax.default_backend() == "tpu":
-            raise NotImplementedError(
-                "mixed_ssd_impl='pallas' does not compile for TPU: Mosaic "
-                "refuses the ragged SSD kernel's (chunk, 1, P) blocks over "
-                "the (T, H, P) token axis (a block's last two dims must "
-                "divide (8, 128) or span the array); use 'ref'")
         if cfg.ssm is not None and cfg.ssm.chunk_size != rcfg.block_size:
             # align SSD chunk boundaries with KV-block boundaries so state
             # snapshots land exactly on block-hash boundaries

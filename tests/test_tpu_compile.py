@@ -4,8 +4,8 @@ The TPU compiler is installed with JAX and compiles for a chip that is
 described, not attached (``jax.experimental.topologies``).  These tests
 compile, at published widths, what the chip's compiler would otherwise
 first refuse on the chip: the ragged paged-attention and grouped-LoRA
-Pallas kernels (granite-3.2-8b), the ragged SSD kernel (mamba2-2.7b,
-still refused), and the whole mixed serving step.  Nothing runs; a
+Pallas kernels (granite-3.2-8b), the ragged SSD kernel (mamba2-2.7b),
+and the whole mixed serving step.  Nothing runs; a
 compile that passes says nothing about results or times.
 
 Only one process may load the TPU library, and it keeps it until it
@@ -20,10 +20,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs import get_chip_share, get_config, get_reduced
+from repro.configs import get_chip_share, get_config
 from repro.core.alora import init_adapter_weights
-from repro.models import init_params, param_specs
-from repro.serving import Engine, EngineConfig
+from repro.models import param_specs
 from repro.serving.runner import RunnerSpec, _mixed_impl
 
 V5E_HBM = 15.75 * (1 << 30)     # what the v5e compiler reports as usable
@@ -101,32 +100,21 @@ def test_grouped_lora_compiles_at_granite_widths(one_chip, T, out):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_ragged_ssd_is_refused_at_mamba2_widths(one_chip):
-    """Mosaic refuses the ragged SSD kernel's (chunk, 1, P) blocks over
-    the (T, H, P) token axis.  When a re-laid-out kernel compiles, this
-    test fails: then drop the engine's refusal below."""
+@pytest.mark.parametrize("T", [16, 128])
+def test_ragged_ssd_compiles_at_mamba2_widths(one_chip, T):
     from repro.kernels.ops import ragged_ssd_scan_op
     S = _shape(one_chip)
     cfg = get_config("mamba2-2.7b")
     s = cfg.ssm
     H = s.expand * cfg.d_model // s.head_dim
-    T, P, N, MR = 128, s.head_dim, s.state_dim, 9
-    f32 = jnp.float32
-    args = (S((T, H, P), jnp.bfloat16), S((T, H, N), f32),
-            S((T, H, N), f32), S((T, H), f32), S((T, H), f32),
-            S((T,), jnp.int32), S((T,), jnp.bool_), S((T,), jnp.int32),
-            S((MR, H, N, P), f32))
-    with pytest.raises(Exception, match="divisible by 8 and 128"):
-        _compile(lambda *a: ragged_ssd_scan_op(*a, chunk=16,
-                                               interpret=False), *args)
-
-
-def test_engine_refuses_pallas_ssd_on_tpu(monkeypatch):
-    cfg = get_reduced("mamba2-2.7b")
-    params = init_params(jax.random.key(0), cfg)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with pytest.raises(NotImplementedError, match="Mosaic refuses"):
-        Engine(cfg, params, engine_cfg=EngineConfig(mixed_ssd_impl="pallas"))
+    P, N, MR, f32 = s.head_dim, s.state_dim, 9, jnp.float32
+    c = _compile(lambda *a: ragged_ssd_scan_op(*a, chunk=16,
+                                               interpret=False),
+                 S((T, H, P), f32), S((T, H, N), f32), S((T, H, N), f32),
+                 S((T, H), f32), S((T, H), f32), S((T,), jnp.int32),
+                 S((T,), jnp.bool_), S((T,), jnp.int32),
+                 S((MR, H, N, P), f32))
+    assert "tpu_custom_call" in c.as_text()
 
 
 def mixed_step_args(cfg, sharding, *, T: int, R: int, nbb: int,
