@@ -21,7 +21,8 @@ path, ``launch/serve.py``'s ``build_engine`` -> ``Engine`` ->
      blocks;
   3. reference: every generated token is checked against
      ``models.model.forward_full`` on the same parameters (see
-     ``LOGIT_TOL``).
+     ``LOGIT_TOL``); the adapter tokens must fail the same check
+     against references that drop the adapters or swap their slots.
 
 Four chips (``--chips 4``): the 20-layer share served tensor-parallel
 over a (data=1, model=4) mesh (``EngineConfig.mesh``) against the same
@@ -59,12 +60,14 @@ EVAL_LEN = 16
 # forward_full both run the model in bf16 (8-bit mantissa), in different
 # orders (paged K/V gathered per token and 128-token prefill chunks,
 # against whole-sequence flash attention), and with random weights the
-# top logits of a 49k vocabulary lie within a fraction of a standard
-# deviation of each other.  On a v5e that reordering moved the worst
-# token 0.111 std below the maximum over 192 tokens (181 argmax-equal).
-# An 8-bit float (3-bit mantissa, 16x coarser) would move it ~16x as
-# far; a token drawn at random sits ~4.5 std below.  0.25 std sits
-# between the two.
+# top two logits are a median 0.15 std apart.  On a v5e that reordering
+# put the worst token 0.05-0.12 std below the maximum (one chip, 192
+# tokens a run) and the first token of each request, the prefill's, at
+# 0.  Two controls, run with every adapter check, must fail it: the same
+# tokens against a reference with the adapters dropped, and with their
+# slots swapped.  Adapters at init_adapter_weights' scale left those
+# controls only 0.27 and 0.63 std off; with launch/serve.py's
+# ADAPTER_GAIN they sat 2.61 and 3.41 std off.
 LOGIT_TOL = 0.25
 GIB = 1 << 30
 
@@ -276,11 +279,25 @@ def serve_batch(cfg, params, engine_cfg, *, prompt_len: int, gen_len: int):
     return eng, [base, alora]
 
 
+def token_gaps(lg, tokens):
+    """``(ref max - ref[token]) / std`` of each reference logit row
+    ``lg[j, k]`` (real vocabulary only) for the engine's ``tokens[j][k]``,
+    and how many tokens are the row's argmax."""
+    import numpy as np
+    tok = np.asarray(tokens)
+    picked = np.take_along_axis(lg, tok[..., None], -1)[..., 0]
+    gaps = (lg.max(-1) - picked) / lg.std(-1)
+    return gaps, int((lg.argmax(-1) == tok).sum())
+
+
 def check_reference(eng, groups, cfg, params) -> None:
     """Teacher-force each request's prompt and generated tokens through
     ``forward_full`` on ``params`` and check every generated token
-    (``LOGIT_TOL``).  ``groups``: lists of request ids whose prompt and
-    output lengths agree (one forward batch each)."""
+    (``LOGIT_TOL``); the first one is the prefill's.  ``groups``: lists
+    of request ids whose prompt and output lengths agree (one forward
+    batch each).  For adapter requests two controls show that the check
+    sees the adapter: the same tokens against a reference that drops the
+    adapters, and against one that swaps their slots, must fail it."""
     from functools import partial
 
     import jax
@@ -295,41 +312,52 @@ def check_reference(eng, groups, cfg, params) -> None:
     stacked = stack_adapters(cfg, [w for _, w in adapters],
                              PAPER_ALORA_RANK)
     slot_of = {s.name: i + 1 for i, (s, _) in enumerate(adapters)}
+    swapped = {s.name: N_ADAPTERS - i for i, (s, _) in enumerate(adapters)}
 
     @partial(jax.jit, static_argnums=4)
     def ref_logits(p, st, toks, aidx, n_last: int):
         with jax.default_matmul_precision("highest"):
             h, _, _ = forward_full(p, cfg, toks, adapters=st,
                                    adapter_idx=aidx)
-            return logits_for(p, cfg, h[:, -n_last:]).astype(jnp.float32)
+            lg = logits_for(p, cfg, h[:, -n_last:])
+            return lg[..., :cfg.vocab_size].astype(jnp.float32)
 
     for ids in groups:
-        worst, agree, n = 0.0, 0, 0
         reqs = [eng.request(i) for i in ids]
         seqs = [r.prompt + r.output_tokens[:-1] for r in reqs]
         L = len(seqs[0])
         check(all(len(s) == L for s in seqs), "ragged reference batch")
-        n_out = len(reqs[0].output_tokens)
-        aidx = np.zeros((len(reqs), L), np.int32)
-        for j, r in enumerate(reqs):
-            if r.adapter is not None:
-                start = find_invocation_start(r.prompt,
-                                              r.adapter.invocation_tokens)
-                aidx[j, start:] = slot_of[r.adapter.name]
-        lg = np.asarray(ref_logits(params, stacked, jnp.asarray(seqs),
-                                   jnp.asarray(aidx), n_out))
-        for j, r in enumerate(reqs):
-            for k, t in enumerate(r.output_tokens):
-                row = lg[j, k]
-                gap = (row.max() - row[t]) / row.std()
-                worst = max(worst, gap)
-                agree += int(row.argmax() == t)
-                n += 1
+        outs = [r.output_tokens for r in reqs]
+        n = sum(map(len, outs))
+
+        def gaps(st, slots):
+            aidx = np.zeros((len(reqs), L), np.int32)
+            for j, r in enumerate(reqs):
+                if r.adapter is not None:
+                    start = find_invocation_start(
+                        r.prompt, r.adapter.invocation_tokens)
+                    aidx[j, start:] = slots[r.adapter.name]
+            aidx = None if st is None else jnp.asarray(aidx)
+            lg = ref_logits(params, st, jnp.asarray(seqs), aidx,
+                            len(outs[0]))
+            return token_gaps(np.asarray(lg), outs)
+
+        g, agree = gaps(stacked, slot_of)
         kind = "adapter" if reqs[0].adapter is not None else "base"
         print(f"  reference, {len(reqs)} {kind} requests: {n} generated "
-              f"tokens; worst gap (ref max - ref[token]) {worst:.4f} std "
-              f"(tol {LOGIT_TOL}); argmax equal on {agree}/{n}")
-        check(worst <= LOGIT_TOL, f"{kind} token off the reference")
+              f"tokens; worst gap (ref max - ref[token]) {g.max():.4f} std, "
+              f"first (prefill) tokens {g[:, 0].max():.4f} (tol "
+              f"{LOGIT_TOL}); argmax equal on {agree}/{n}")
+        check(g.max() <= LOGIT_TOL, f"{kind} token off the reference")
+        if kind == "base":
+            continue
+        for what, st, slots in (("adapters dropped", None, slot_of),
+                                ("adapter slots swapped", stacked, swapped)):
+            g, agree = gaps(st, slots)
+            print(f"    control, reference with {what}: worst gap "
+                  f"{g.max():.4f} std; argmax equal on {agree}/{n}")
+            check(g.max() > LOGIT_TOL,
+                  f"the reference check cannot see the adapter ({what})")
 
 
 def one_chip() -> None:
@@ -367,13 +395,10 @@ def four_chips() -> None:
     import gc
 
     import jax
-    import jax.numpy as jnp
-    import numpy as np
     from repro.configs import get_chip_share, get_config
     from repro.distributed import sharding as shd
     from repro.launch.mesh import make_host_mesh
     from repro.models import init_params, param_specs
-    from repro.models.model import forward_full, logits_for
     from repro.serving import EngineConfig
 
     mesh = make_host_mesh(data=1, model=4)
@@ -384,30 +409,13 @@ def four_chips() -> None:
     print("phase 1: 20-layer share, tensor-parallel over 4 chips vs the "
           "same share on one chip")
     share = get_chip_share(ARCH)
-    params = init_params(jax.random.key(SEED), share)      # one chip
+    # the engine shards its own copy; the reference runs on this one, on
+    # one chip: every token of the sharded engine (the first of each
+    # request from its sharded prefill) is checked against one chip
+    params = init_params(jax.random.key(SEED), share)
     eng, groups = serve_batch(share, params, ecfg, **sizes)
     check_reference(eng, groups, share, params)
 
-    @jax.jit
-    def prefill_logits(p, toks):
-        with jax.default_matmul_precision("highest"):
-            h, _, _ = forward_full(p, share, toks)
-            return logits_for(p, share, h[:, -1]).astype(jnp.float32)
-
-    # the 4-chip row's top token, read in the one-chip row: the same
-    # check (and tolerance) as the generated tokens'
-    toks = jnp.asarray([eng.request(i).prompt for i in groups[0]])
-    one = np.asarray(prefill_logits(params, toks))
-    tp = np.asarray(prefill_logits(eng.runner.params, toks))
-    std = one.std(axis=-1)
-    top = tp.argmax(axis=-1)
-    gap = float(((one.max(axis=-1) - one[np.arange(len(top)), top])
-                 / std).max())
-    diff = float((np.abs(tp - one).max(axis=-1) / std).max())
-    print(f"  prefill logits, 4-chip vs one chip: 4-chip top token {gap:.4f}"
-          f" std below the one-chip max (tol {LOGIT_TOL}); max |diff| over "
-          f"the vocabulary {diff:.4f} std")
-    check(gap <= LOGIT_TOL, "4-chip prefill off the one-chip reference")
     del eng, params
     gc.collect()
 

@@ -712,12 +712,15 @@ def decode_step(params: Params, cfg: ModelConfig, token: jax.Array,
 
 def logits_for(params: Params, cfg: ModelConfig, hidden: jax.Array
                ) -> jax.Array:
-    """Logits over the real vocabulary.  The embedding has
-    ``padded_vocab`` rows so it shards evenly; the rows past
-    ``vocab_size`` are not tokens, so they are sliced off here and no
-    sampler can pick them."""
+    """Logits over the embedding's ``padded_vocab`` rows, whose count
+    divides the mesh's model axis, so the logits shard as the embedding
+    does.  The rows past ``vocab_size`` are not tokens: their logits are
+    ``-inf``, so no sampler picks them and a softmax gives them 0."""
     logits = L.unembed(params["embed"], hidden, cfg.tie_embeddings)
-    return logits[..., :cfg.vocab_size]
+    if logits.shape[-1] == cfg.vocab_size:
+        return logits
+    real = jnp.arange(logits.shape[-1]) < cfg.vocab_size
+    return jnp.where(real, logits, -jnp.inf)
 
 
 def prefill_to_decode_caches(cfg: ModelConfig, prefill_caches: Params,

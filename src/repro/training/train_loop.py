@@ -17,7 +17,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import forward_full, logits_for
-from repro.models.layers import padded_vocab
 from repro.models.model import Runtime
 from repro.training.optimizer import (AdamWConfig, AdamWState, adamw_update,
                                       init_adamw)
@@ -33,8 +32,6 @@ def chunked_ce_loss(params, cfg: ModelConfig, hidden: jax.Array,
                     chunk: int = 512) -> jax.Array:
     """Cross entropy with seq-chunked logits.  hidden: (B, S, d)."""
     B, S, _ = hidden.shape
-    V = padded_vocab(cfg)
-    vreal = cfg.vocab_size
     nch = max(S // min(chunk, S), 1)
     ch = S // nch
     h = hidden[:, :nch * ch].reshape(B, nch, ch, -1).swapaxes(0, 1)
@@ -43,12 +40,8 @@ def chunked_ce_loss(params, cfg: ModelConfig, hidden: jax.Array,
 
     def body(carry, inp):
         hc, yc, mc = inp
+        # the padded vocab tail is -inf (logits_for): it adds 0 to lse
         logits = logits_for(params, cfg, hc).astype(jnp.float32)
-        # mask the padded vocab tail
-        neg = jnp.full((V - vreal,), -1e30, jnp.float32) if V > vreal \
-            else None
-        if neg is not None:
-            logits = logits.at[..., vreal:].set(-1e30)
         lse = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, yc[..., None],
                                    axis=-1)[..., 0]

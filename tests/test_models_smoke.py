@@ -57,6 +57,36 @@ def test_forward_shapes_and_finite(models, arch):
     assert bool(jnp.isfinite(logits).all())
 
 
+def test_padded_vocab_tail_is_masked():
+    """With a vocabulary that is not a multiple of 512, the logits keep
+    the embedding's padded width (so they shard like it); the padding
+    rows are -inf, the real rows are the plain unembedding, and the
+    loss and its gradient see only the real rows."""
+    from repro.models import layers as L
+    from repro.training.train_loop import chunked_ce_loss
+    cfg = get_reduced("granite-3.2-8b").replace(vocab_size=500)
+    params = init_params(KEY, cfg)
+    B, S = 2, 16
+    h = jax.random.normal(KEY, (B, S, cfg.d_model))
+    logits = logits_for(params, cfg, h)
+    assert padded_vocab(cfg) > 500
+    assert logits.shape == (B, S, padded_vocab(cfg))
+    assert bool(jnp.isneginf(logits[..., 500:]).all())
+    full = L.unembed(params["embed"], h, cfg.tie_embeddings)
+    np.testing.assert_array_equal(logits[..., :500], full[..., :500])
+    assert int(logits.argmax(-1).max()) < 500
+
+    labels = jax.random.randint(KEY, (B, S), 0, 500)
+    mask = jnp.ones((B, S))
+    loss, grad = jax.value_and_grad(
+        lambda p: chunked_ce_loss(p, cfg, h, labels, mask, chunk=8))(params)
+    real = full[..., :500]
+    want = (jax.nn.logsumexp(real, -1)
+            - jnp.take_along_axis(real, labels[..., None], -1)[..., 0]).mean()
+    np.testing.assert_allclose(loss, want, rtol=1e-5)
+    assert all(bool(jnp.isfinite(g).all()) for g in jax.tree.leaves(grad))
+
+
 @pytest.mark.parametrize("arch", ASSIGNED_ARCHS)
 def test_train_step(models, arch):
     cfg, params = models(arch)
@@ -159,12 +189,15 @@ def test_init_params_matches_per_layer_stacking(arch):
                      got["encoder"]["blocks"], want)
 
 
-def test_init_params_with_shardings_places_each_leaf():
+def check_sharded_init(model: int) -> None:
+    """``init_params(..., shardings)`` on a (data=1, model=``model``)
+    host mesh places every leaf with its sharding, splits some over
+    ``model``, and gives the values of the unsharded init."""
     from repro.distributed import sharding as shd
     from repro.launch.mesh import make_host_mesh
     from repro.models import param_specs
     cfg = get_reduced("granite-3.2-8b")
-    mesh = make_host_mesh()
+    mesh = make_host_mesh(data=1, model=model)
     named = shd.to_named(shd.param_specs_tree(cfg, param_specs(cfg),
                                               mesh=mesh), mesh)
     got = init_params(jax.random.key(0), cfg, named)
@@ -172,3 +205,30 @@ def test_init_params_with_shardings_places_each_leaf():
     jax.tree.map(np.testing.assert_array_equal, got, want)
     for a, s in zip(jax.tree.leaves(got), jax.tree.leaves(named)):
         assert a.sharding.is_equivalent_to(s, a.ndim)
+    assert any(not a.sharding.is_fully_replicated
+               for a in jax.tree.leaves(got["blocks"]))
+
+
+def test_init_params_with_shardings_places_each_leaf():
+    """Sharded init on 4 host devices.  A process with fewer (the plain
+    one-device run) checks it in a child process started with 4."""
+    n = 4
+    if jax.device_count() >= n:
+        check_sharded_init(n)
+        return
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    here = Path(__file__).resolve().parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=os.environ.get("XLA_FLAGS", "")
+               + f" --xla_force_host_platform_device_count={n}",
+               PYTHONPATH=os.pathsep.join(
+                   [str(here.parent / "src"), str(here),
+                    os.environ.get("PYTHONPATH", "")]))
+    r = subprocess.run(
+        [sys.executable, "-c",
+         f"import test_models_smoke as t; t.check_sharded_init({n})"],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-4000:]

@@ -5,7 +5,8 @@ described, not attached (``jax.experimental.topologies``).  These tests
 compile, at published widths, what the chip's compiler would otherwise
 first refuse on the chip: the ragged paged-attention and grouped-LoRA
 Pallas kernels (granite-3.2-8b), the ragged SSD kernel (mamba2-2.7b),
-and the whole mixed serving step.  Nothing runs; a
+and the whole mixed serving step; and they lower the dry-run's sharded
+prefill and decode steps on a 4-chip mesh.  Nothing runs; a
 compile that passes says nothing about results or times.
 
 Only one process may load the TPU library, and it keeps it until it
@@ -17,6 +18,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -145,6 +147,27 @@ def mixed_step_args(cfg, sharding, *, T: int, R: int, nbb: int,
             S((2,), i32), S((R, nbb), i32), tok, tok, tok, tok,
             S((R,), i32), S((R,), i32), tok, S((8,), i32), None)
     return spec, args
+
+
+@pytest.mark.parametrize("shape", ["prefill_32k", "decode_32k"])
+def test_dryrun_step_lowers_with_odd_vocab(topo, shape):
+    """The dry-run's prefill and decode steps shard the logits' vocab
+    axis over ``model``.  granite-3.2-8b's 49,155 tokens divide by no
+    model axis; the logits keep the embedding's padded rows, so the
+    step lowers on a (data=1, model=4) mesh."""
+    from jax.sharding import Mesh
+
+    from repro.configs import INPUT_SHAPES
+    from repro.launch.steps import build_step
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, -1), ("data", "model"))
+    spec = build_step(GRANITE, INPUT_SHAPES[shape], mesh)
+    with mesh:
+        lowered = jax.jit(spec.fn, in_shardings=spec.in_shardings,
+                          out_shardings=spec.out_shardings,
+                          donate_argnums=spec.donate_argnums
+                          ).lower(*spec.args)
+    logits = lowered.out_info[0]
+    assert logits.shape[-1] % mesh.shape["model"] == 0
 
 
 @pytest.mark.parametrize("impl", ["ref", "pallas"])
