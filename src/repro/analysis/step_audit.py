@@ -340,13 +340,13 @@ def capture_batch(eng: Engine, n: int = 3, gen: int = 4,
     cfg = eng.cfg
     rng = np.random.RandomState(5)
     captured: List[MixedBatch] = []
-    orig = eng.runner.submit_batch
+    orig = eng.runner._assemble_mixed
 
     def cap(mb: MixedBatch):
         captured.append(mb)
         return orig(mb)
 
-    eng.runner.submit_batch = cap  # type: ignore[method-assign]
+    eng.runner._assemble_mixed = cap  # type: ignore[method-assign]
     try:
         for i in range(n):
             kw = {}
@@ -361,7 +361,7 @@ def capture_batch(eng: Engine, n: int = 3, gen: int = 4,
             eng.step()
             steps += 1
     finally:
-        eng.runner.submit_batch = orig  # type: ignore[method-assign]
+        eng.runner._assemble_mixed = orig  # type: ignore[method-assign]
     if not captured:
         raise RuntimeError(f"no mixed batch captured for {cfg.name}")
     return max(captured,

@@ -184,26 +184,29 @@ def lora_delta_dispatch(x: jax.Array, a_stack: jax.Array,
     "pallas"/"pallas_interpret" — the SGMV-style Pallas kernel.
 
     x / adapter_idx may carry leading batch dims; the grouped paths
-    flatten them onto the token axis.
+    flatten them onto the token axis.  The delta's ops run under the
+    named scope ``lora``.
     """
-    if impl == "dense" or active_slots is None:
-        return lora_delta(x, a_stack, b_stack, adapter_idx)
-    lead = x.shape[:-1]
-    x2 = x.reshape(-1, x.shape[-1])
-    idx2 = adapter_idx.reshape(-1)
-    if impl == "ref":
-        from repro.kernels.ragged_lora import ragged_grouped_lora_ref
-        d = ragged_grouped_lora_ref(x2, a_stack, b_stack, idx2,
-                                    active_slots)
-    elif impl in ("pallas", "pallas_interpret"):
-        from repro.kernels.ragged_lora import ragged_grouped_lora_padded
-        d = ragged_grouped_lora_padded(
-            x2, a_stack, b_stack, idx2, active_slots,
-            interpret=(impl == "pallas_interpret"))
-    else:
-        raise ValueError(f"unknown grouped-LoRA impl {impl!r}: expected "
-                         "'dense', 'ref', 'pallas' or 'pallas_interpret'")
-    return d.reshape(lead + (d.shape[-1],))
+    with jax.named_scope("lora"):
+        if impl == "dense" or active_slots is None:
+            return lora_delta(x, a_stack, b_stack, adapter_idx)
+        lead = x.shape[:-1]
+        x2 = x.reshape(-1, x.shape[-1])
+        idx2 = adapter_idx.reshape(-1)
+        if impl == "ref":
+            from repro.kernels.ragged_lora import ragged_grouped_lora_ref
+            d = ragged_grouped_lora_ref(x2, a_stack, b_stack, idx2,
+                                        active_slots)
+        elif impl in ("pallas", "pallas_interpret"):
+            from repro.kernels.ragged_lora import ragged_grouped_lora_padded
+            d = ragged_grouped_lora_padded(
+                x2, a_stack, b_stack, idx2, active_slots,
+                interpret=(impl == "pallas_interpret"))
+        else:
+            raise ValueError(f"unknown grouped-LoRA impl {impl!r}: "
+                             "expected 'dense', 'ref', 'pallas' or "
+                             "'pallas_interpret'")
+        return d.reshape(lead + (d.shape[-1],))
 
 
 def qkv_project(p: Params, cfg: ModelConfig, x: jax.Array,

@@ -3,7 +3,7 @@
 ``repro.obs.tracer`` is the hot-path-safe recording core (plain-python
 appends only — linted wholesale by ``repro.analysis.hotpath_lint``);
 ``repro.obs.export`` renders the recorded rings into Perfetto JSON,
-Prometheus text and JSONL off the step path.  See
+Prometheus text and summary tables off the step path.  See
 ``docs/observability.md`` for the trace schema and track layout.
 """
 from repro.obs.export import (
@@ -11,8 +11,6 @@ from repro.obs.export import (
     prometheus_text,
     reuse_by_adapter,
     to_perfetto,
-    trace_records,
-    write_jsonl,
     write_perfetto,
 )
 from repro.obs.tracer import (
@@ -31,7 +29,5 @@ __all__ = [
     "reuse_by_adapter",
     "to_perfetto",
     "trace_enabled_default",
-    "trace_records",
-    "write_jsonl",
     "write_perfetto",
 ]

@@ -3,23 +3,20 @@ artifacts.  Runs strictly OFF the step path (after a run, or from a
 benchmark/CLI), so unlike ``repro.obs.tracer`` this module may do real
 work: JSON encoding, byte accounting, aggregation.
 
-Three formats:
+Two formats:
 
 * **Perfetto / Chrome trace JSON** (``to_perfetto``/``write_perfetto``):
   load the file at https://ui.perfetto.dev.  One process per replica
-  carrying the step-phase tracks (schedule / submit / retire / pool) on
-  the WALL-clock timebase — per-replica submit/retire overlap and fleet
-  concurrency are wall-clock facts and render as literally overlapping
-  slices — plus one process per replica for request lifecycles
+  carrying the step-phase tracks (step / schedule / submit / retire /
+  pool) on the WALL-clock timebase — per-replica submit/retire overlap
+  and fleet concurrency are wall-clock facts and render as literally
+  overlapping slices — plus one process per replica for request lifecycles
   (queue → prefill → decode spans per request) on the VIRTUAL-clock
   timebase, and one process for the router's placement decisions.
 * **Prometheus text** (``prometheus_text``): a flat counters snapshot in
   the text exposition format, one ``repro_*`` counter family per
   ``Tracer.counters`` key with a ``replica`` label — the scrape payload
   ``launch/serve.py --metrics-out`` writes.
-* **JSONL** (``trace_records``/``write_jsonl``): every event, ledger row
-  and counter as a flat dict — the form ``benchmarks/report.py``
-  consumes for the per-adapter reuse table.
 
 Schema details and the track layout live in ``docs/observability.md``.
 """
@@ -30,7 +27,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.obs.tracer import EVENT_FIELDS, LEDGER_FIELDS, Tracer
+from repro.obs.tracer import Tracer
 
 # Perfetto process-id layout: phase tracks at PID_PHASE+replica,
 # request lifecycles at PID_LIFECYCLE+replica, the router at PID_ROUTER
@@ -39,7 +36,7 @@ PID_LIFECYCLE = 1001
 PID_ROUTER = 2001
 # thread id per phase track inside a replica's phase process
 TRACK_TIDS = {"schedule": 1, "submit": 2, "retire": 3, "pool": 4,
-              "router": 5, "lifecycle": 6}
+              "router": 5, "lifecycle": 6, "step": 7}
 
 
 def _us(t: Optional[float]) -> float:
@@ -66,12 +63,16 @@ def to_perfetto(tracers: Sequence[Tracer]) -> Dict[str, Any]:
     plus optionally the router's)."""
     ev: List[Dict[str, Any]] = []
     for tr in tracers:
+        # a trimmed ring says so in its process name
+        dropped = f" · {tr.dropped} oldest records dropped" \
+            if tr.dropped else ""
         if tr.replica < 0:          # the router's own tracer
             pid_phase = PID_ROUTER
-            ev += _meta(pid_phase, "router")
+            ev += _meta(pid_phase, "router" + dropped)
         else:
             pid_phase = PID_PHASE + tr.replica
-            ev += _meta(pid_phase, f"replica {tr.replica} · step phases")
+            ev += _meta(pid_phase,
+                        f"replica {tr.replica} · step phases" + dropped)
         pid_life = PID_LIFECYCLE + max(tr.replica, 0)
         named_tids = set()
         life_named = False
@@ -154,36 +155,6 @@ def to_perfetto(tracers: Sequence[Tracer]) -> Dict[str, Any]:
 def write_perfetto(path: str, tracers: Sequence[Tracer]) -> None:
     with open(path, "w") as f:
         json.dump(to_perfetto(tracers), f)
-
-
-# ---------------------------------------------------------------------------
-def trace_records(tracers: Sequence[Tracer]) -> List[Dict[str, Any]]:
-    """Every event + ledger row + counter as flat JSONL-able dicts (the
-    ``benchmarks/report.py`` input)."""
-    out: List[Dict[str, Any]] = []
-    for tr in tracers:
-        for evt in tr.events:
-            rec = dict(zip(EVENT_FIELDS, evt))
-            rec["replica"] = tr.replica
-            out.append(rec)
-        for row in tr.ledger:
-            rec = dict(zip(LEDGER_FIELDS, row))
-            rec["kind"] = "ledger"
-            rec["replica"] = tr.replica
-            out.append(rec)
-        for name, val in sorted(tr.counters.items()):
-            out.append({"kind": "counter", "name": name, "value": val,
-                        "replica": tr.replica})
-        if tr.dropped:
-            out.append({"kind": "dropped", "value": tr.dropped,
-                        "replica": tr.replica})
-    return out
-
-
-def write_jsonl(path: str, tracers: Sequence[Tracer]) -> None:
-    with open(path, "w") as f:
-        for rec in trace_records(tracers):
-            f.write(json.dumps(rec) + "\n")
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,14 @@ escape hatch (rule ``obs-jax``/``obs-sync``).  Anything that needs
 real work — byte accounting, JSON, aggregation — belongs in
 ``repro.obs.export``, which only ever runs off the step path.
 
+Engine phases (``Tracer.phase``) are the one place recording meets the
+profiler: each opens a host annotation named ``engine.<name>`` from the
+factory the tracer was constructed with (the engine passes
+``jax.profiler.TraceAnnotation``, so this module itself calls no jax),
+which lands on the profiler's clock beside the device planes, and on
+exit records the same interval as a ring span.  A disabled tracer hands
+out one shared no-op context instead.
+
 Two timestamps ride every record:
 
 * ``t0``/``t1`` — host wall time (``time.perf_counter()`` seconds):
@@ -38,25 +46,24 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 # bulk-trim bounds for the event + ledger rings (oldest half dropped at
 # the threshold, mirroring runner.D2H_LOG_MAX/KEEP)
 TRACE_RING_MAX = 65536
 TRACE_RING_KEEP = 32768
 
-# event-record field order (a plain tuple per record — the stable
-# schema ``repro.obs.export`` renders; tests golden it)
-EVENT_FIELDS = ("kind", "track", "name", "t0", "t1", "vclock", "args")
-# ledger-record field order
-LEDGER_FIELDS = ("req_id", "adapter_uid", "reused", "recomputed",
-                 "state_reused", "vclock")
-
 # track vocabulary (Perfetto thread per track, see docs/observability.md)
-TRACKS = ("schedule", "submit", "retire", "pool", "router", "lifecycle")
+TRACKS = ("schedule", "submit", "retire", "pool", "router", "lifecycle",
+          "step")
+# profiler annotation prefix of an engine phase (``Tracer.phase``)
+PHASE_PREFIX = "engine."
 
+# a plain tuple per record — the stable schema ``repro.obs.export``
+# renders; tests golden it: (kind, track, name, t0, t1, vclock, args)
 EventRec = Tuple[str, str, str, float, float, Optional[float],
                  Optional[Dict[str, Any]]]
+# (req_id, adapter_uid, reused, recomputed, state_reused, vclock)
 LedgerRec = Tuple[int, Optional[str], int, int, bool, Optional[float]]
 
 
@@ -65,17 +72,74 @@ def trace_enabled_default() -> bool:
     return os.environ.get("REPRO_TRACE", "1") != "0"
 
 
+class _NoPhase:
+    """What a disabled tracer's ``phase`` returns: one shared context
+    that enters and exits and records nothing."""
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoPhase":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NO_PHASE = _NoPhase()
+
+
+class Phase:
+    """One open engine phase: a profiler annotation ``engine.<name>``
+    (when the tracer has an annotation factory) around the body, and a
+    ring span on ``track`` recorded on exit, stamped with the tracer's
+    virtual clock at that moment.  ``args`` may be set inside the body;
+    they ride the ring span only."""
+    __slots__ = ("tracer", "track", "name", "args", "_ann", "_t0")
+
+    def __init__(self, tracer: "Tracer", track: str, name: str):
+        self.tracer, self.track, self.name = tracer, track, name
+        self.args: Optional[Dict[str, Any]] = None
+        self._ann: Any = None
+        self._t0 = 0.0
+
+    def __enter__(self) -> "Phase":
+        annotate = self.tracer.annotate
+        if annotate is not None:
+            self._ann = annotate(PHASE_PREFIX + self.name)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        tr = self.tracer
+        tr.span(self.track, self.name, self._t0, t1,
+                None if tr.clock is None else tr.clock(), self.args)
+        return False
+
+
 class Tracer:
     """Bounded-ring trace recorder (one per engine / router).
 
     All recording methods are O(1) plain-python appends and early-return
     when disabled — safe to call from schedule/submit-phase code.
+
+    ``annotate`` (a context-manager factory taking a name) opens the
+    profiler annotation of each ``phase``; ``clock`` returns the virtual
+    clock a phase's span is stamped with.  Both come from whoever
+    constructs the tracer: the engine passes
+    ``jax.profiler.TraceAnnotation`` and its own clock.
     """
 
-    def __init__(self, enabled: Optional[bool] = None, replica: int = 0):
+    def __init__(self, enabled: Optional[bool] = None, replica: int = 0,
+                 annotate: Optional[Callable[[str], Any]] = None,
+                 clock: Optional[Callable[[], float]] = None):
         self.enabled = trace_enabled_default() if enabled is None \
             else bool(enabled)
         self.replica = replica
+        self.annotate = annotate
+        self.clock = clock
         self.events: List[EventRec] = []
         self.ledger: List[LedgerRec] = []
         self.counters: Dict[str, float] = {}
@@ -104,6 +168,14 @@ class Tracer:
             return
         self._append(self.events, ("span", track, name, t0, t1, vclock,
                                    args))
+
+    def phase(self, track: str, name: str):
+        """Context manager for one engine phase (see :class:`Phase`); a
+        disabled tracer returns the shared ``NO_PHASE``, so the off path
+        allocates nothing and the profiler sees nothing."""
+        if not self.enabled:
+            return NO_PHASE
+        return Phase(self, track, name)
 
     def event(self, track: str, name: str, vclock: Optional[float],
               args: Optional[Dict[str, Any]] = None) -> None:

@@ -40,6 +40,7 @@ the per-step device→host payload is a handful of int32 ids instead of
 from __future__ import annotations
 
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass
 from itertools import islice
@@ -203,8 +204,13 @@ class Engine:
         adapters = adapters or []
         # trace recorder (repro.obs): created FIRST so the adapter pool
         # and runner can stamp events into the same per-replica rings;
-        # the router re-stamps replica ids after construction
-        self.tracer = Tracer(enabled=engine_cfg.trace)
+        # the router re-stamps replica ids after construction.  Its
+        # phases open profiler annotations (``engine.<phase>``), on the
+        # device trace's clock
+        me = weakref.ref(self)     # the tracer must not keep us alive
+        self.tracer = Tracer(enabled=engine_cfg.trace,
+                             annotate=jax.profiler.TraceAnnotation,
+                             clock=lambda: me().clock)
         # dynamic adapter pool: construction-time adapters are ordinary
         # registrations; more can be registered/unregistered at any time
         # and cycle through the fixed device slots (heterogeneous ranks
@@ -602,6 +608,19 @@ class Engine:
         # move due arrivals into the waiting queue
         while self.pending and self.pending[0].arrival_time <= self.clock:
             self.waiting.append(self.pending.popleft())
+        # idle: expire adapter stages, then jump to the next arrival
+        if not self.waiting and not self.running:
+            if self.adapter_pool is not None:
+                self.adapter_pool.tick()
+            if self.pending:
+                self.clock = self.pending[0].arrival_time
+            return 0.0
+        with self.tracer.phase("step", "step"):
+            return self._step()
+
+    def _step(self) -> float:
+        """``step`` with work to do, inside its ``step`` phase."""
+        tr = self.tracer
         # scheduler-driven adapter prefetch: issue the async host→device
         # transfer for every adapter an admission-window request will
         # need, so the weights are staged (or already in flight) by the
@@ -614,83 +633,78 @@ class Engine:
         # budget, not the window; tick() first so expired stages free
         # budget for this step's prefetches.
         if self.adapter_pool is not None:
-            self.adapter_pool.tick()
-            for r in islice(self.waiting, self.ecfg.admission_window):
-                if r.adapter_uid is not None:
-                    self.adapter_pool.prefetch(r.adapter_uid)
-        # idle: jump to the next arrival
-        if not self.waiting and not self.running:
-            if self.pending:
-                self.clock = self.pending[0].arrival_time
-                return 0.0
-            return 0.0
+            with tr.phase("step", "prefetch"):
+                self.adapter_pool.tick()
+                for r in islice(self.waiting, self.ecfg.admission_window):
+                    if r.adapter_uid is not None:
+                        self.adapter_pool.prefetch(r.adapter_uid)
 
         t_before = self.clock
         prev = self._inflight
         self._inflight = None
-        tr = self.tracer
-        t_sched0 = time.perf_counter()
 
         # ---- schedule ------------------------------------------------
-        # decode first: running requests claim their next block BEFORE
-        # admission can hand freed blocks to new/preempted requests —
-        # this (plus recompute-preemption below) guarantees progress
-        # under block starvation (vLLM's decode-priority scheduling)
-        decodes = self._schedule_decodes()
-        n_decode = len(decodes)
+        with tr.phase("schedule", "schedule") as ph:
+            # decode first: running requests claim their next block
+            # BEFORE admission can hand freed blocks to new/preempted
+            # requests — this (plus recompute-preemption below)
+            # guarantees progress under block starvation (vLLM's
+            # decode-priority scheduling)
+            decodes = self._schedule_decodes()
+            n_decode = len(decodes)
 
-        # admission: adapter-aware windowed scan (default) or the strict
-        # FCFS-with-break oracle (EngineConfig.admission_policy="fcfs")
-        if self.ecfg.admission_policy == "fcfs":
-            while self.waiting \
-                    and len(self.running) < self.ecfg.max_running:
-                if not self._try_admit(self.waiting[0]):
-                    break
-                self.waiting.popleft()
-        else:
-            self._admit_affinity()
+            # admission: adapter-aware windowed scan (default) or the
+            # strict FCFS-with-break oracle
+            # (EngineConfig.admission_policy="fcfs")
+            with tr.phase("schedule", "admit"):
+                if self.ecfg.admission_policy == "fcfs":
+                    while self.waiting \
+                            and len(self.running) < self.ecfg.max_running:
+                        if not self._try_admit(self.waiting[0]):
+                            break
+                        self.waiting.popleft()
+                else:
+                    self._admit_affinity()
 
-        # chunked-prefill budget: whatever the decodes left of
-        # max_batched_tokens, minus last step's minimum-progress
-        # overdraft.  Only when NO decode ran may prefill overdraw by one
-        # block (minimum progress); the overdraft is charged to the next
-        # step instead of silently violating the cap.
-        avail = self.ecfg.max_batched_tokens - n_decode - self._budget_debt
-        budget = avail
-        if n_decode == 0 and budget < self.ecfg.block_size:
-            budget = self.ecfg.block_size
-        prefills = self._schedule_prefills(budget)
-        n_prefill = sum(hi - lo for _, lo, hi in prefills)
-        # everything spent this step (decodes are non-deferrable) plus
-        # inherited debt beyond the cap carries forward — debt is paid
-        # down by under-cap steps, never silently forgiven
-        self._budget_debt = max(0, n_decode + n_prefill
-                                + self._budget_debt
-                                - self.ecfg.max_batched_tokens)
-        self.last_step_tokens = (n_decode, n_prefill)
-        if tr.enabled:
-            tr.span("schedule", "schedule", t_sched0,
-                    time.perf_counter(), self.clock,
-                    {"n_decode": n_decode, "n_prefill": n_prefill,
-                     "running": len(self.running),
-                     "waiting": len(self.waiting)})
-            tr.count("steps_total")
-            tr.count("decode_tokens_total", n_decode)
-            tr.count("prefill_tokens_total", n_prefill)
+            # chunked-prefill budget: whatever the decodes left of
+            # max_batched_tokens, minus last step's minimum-progress
+            # overdraft.  Only when NO decode ran may prefill overdraw by
+            # one block (minimum progress); the overdraft is charged to
+            # the next step instead of silently violating the cap.
+            avail = self.ecfg.max_batched_tokens - n_decode \
+                - self._budget_debt
+            budget = avail
+            if n_decode == 0 and budget < self.ecfg.block_size:
+                budget = self.ecfg.block_size
+            prefills = self._schedule_prefills(budget)
+            n_prefill = sum(hi - lo for _, lo, hi in prefills)
+            # everything spent this step (decodes are non-deferrable)
+            # plus inherited debt beyond the cap carries forward — debt
+            # is paid down by under-cap steps, never silently forgiven
+            self._budget_debt = max(0, n_decode + n_prefill
+                                    + self._budget_debt
+                                    - self.ecfg.max_batched_tokens)
+            self.last_step_tokens = (n_decode, n_prefill)
+            if tr.enabled:
+                ph.args = {"n_decode": n_decode, "n_prefill": n_prefill,
+                           "running": len(self.running),
+                           "waiting": len(self.waiting)}
+                tr.count("steps_total")
+                tr.count("decode_tokens_total", n_decode)
+                tr.count("prefill_tokens_total", n_prefill)
 
         # ---- submit --------------------------------------------------
         if self.use_mixed:
-            t_sub0 = time.perf_counter()
-            asm0 = self.t_assembly + self.runner.t_assembly
-            inflight = self._submit_mixed(decodes, prefills)
-            if tr.enabled and inflight is not None:
-                # covers host-side batch assembly (HostBufferPool take +
-                # pack, runner _dev_meta staging) AND the jitted dispatch
-                tr.span("submit", "submit", t_sub0, time.perf_counter(),
-                        self.clock,
-                        {"n_decode": n_decode, "n_prefill": n_prefill,
-                         "t_assembly": self.t_assembly
-                         + self.runner.t_assembly - asm0})
+            with tr.phase("submit", "submit") as ph:
+                asm0 = self.t_assembly + self.runner.t_assembly
+                inflight = self._submit_mixed(decodes, prefills)
+                if tr.enabled:
+                    # covers host-side batch assembly (HostBufferPool
+                    # take + pack, runner _dev_meta staging) AND the
+                    # jitted dispatch
+                    ph.args = {"n_decode": n_decode, "n_prefill": n_prefill,
+                               "t_assembly": self.t_assembly
+                               + self.runner.t_assembly - asm0}
             if inflight is not None and prev is not None:
                 self.async_overlap_steps += 1
             if not self.use_async and inflight is not None:
@@ -991,6 +1005,39 @@ class Engine:
                       ) -> Optional[_InflightStep]:
         if not decodes and not prefills:
             return None
+        tr = self.tracer
+        with tr.phase("submit", "assemble"):
+            mb, span_snaps = self._pack_mixed(decodes, prefills)
+            t0 = time.perf_counter()
+            args = self.runner._assemble_mixed(mb)
+        with tr.phase("submit", "dispatch"):
+            # one jitted call, no sync
+            handle = self.runner.dispatch_mixed(args, len(mb.block_tables))
+            self.clock += (time.perf_counter() - t0) * self.ecfg.time_scale
+        # eager (token-value-free) bookkeeping; the retire list records
+        # what must wait for the sampled ids.  Decode rows first, then
+        # prefill — the same order the sequential path registers blocks
+        retires: List[Tuple] = []
+        for i, r in enumerate(decodes):
+            patch_idx, bpos, slot = self._advance_decode(r)
+            retires.append((r, r.epoch, i, patch_idx, bpos, slot))
+        for j, (r, lo, hi) in enumerate(prefills):
+            bnd = None
+            if handle.boundary is not None:
+                off, cnt = span_snaps[j]
+                bnd = (handle.boundary[0][:, off:off + cnt],
+                       handle.boundary[1][:, off:off + cnt])
+            patch_idx = self._advance_prefill(r, lo, hi, bnd)
+            retires.append((r, r.epoch, len(decodes) + j, patch_idx,
+                            None, None))
+        return _InflightStep(handle=handle, retires=retires)
+
+    def _pack_mixed(self, decodes: List[Request],
+                    prefills: List[Tuple[Request, int, int]]
+                    ) -> Tuple[MixedBatch, List[Tuple[int, int]]]:
+        """Pack the step's decode tokens and prefill chunks into one
+        ragged :class:`MixedBatch`; also returns each prefill span's
+        (offset, count) into the batch's ``snap_rows``."""
         t_host = time.perf_counter()
         bs = self.ecfg.block_size
         reqs = decodes + [r for r, _, _ in prefills]
@@ -1083,38 +1130,18 @@ class Engine:
                         xkv_list=xkv_list,
                         active_slots=np.array(active, np.int32))
         self.t_assembly += time.perf_counter() - t_host
-        t0 = time.perf_counter()
-        handle = self.runner.submit_batch(mb)   # one jitted call, no sync
-        self.clock += (time.perf_counter() - t0) * self.ecfg.time_scale
-        # eager (token-value-free) bookkeeping; the retire list records
-        # what must wait for the sampled ids.  Decode rows first, then
-        # prefill — the same order the sequential path registers blocks
-        retires: List[Tuple] = []
-        for i, r in enumerate(decodes):
-            patch_idx, bpos, slot = self._advance_decode(r)
-            retires.append((r, r.epoch, i, patch_idx, bpos, slot))
-        for j, (r, lo, hi) in enumerate(prefills):
-            bnd = None
-            if handle.boundary is not None:
-                off, cnt = span_snaps[j]
-                bnd = (handle.boundary[0][:, off:off + cnt],
-                       handle.boundary[1][:, off:off + cnt])
-            patch_idx = self._advance_prefill(r, lo, hi, bnd)
-            retires.append((r, r.epoch, len(decodes) + j, patch_idx,
-                            None, None))
-        return _InflightStep(handle=handle, retires=retires)
+        return mb, span_snaps
 
     # ------------------------------------------------------------------
     def _retire_traced(self, inf: Optional[_InflightStep]) -> None:
-        """``_retire`` wrapped in the retire-phase trace span (covers
-        the one sanctioned D2H sync + the deferred bookkeeping)."""
+        """``_retire`` inside the ``retire`` phase (covers the one
+        sanctioned D2H sync + the deferred bookkeeping)."""
         if inf is None:
             return
-        t0 = time.perf_counter()
-        self._retire(inf)
-        if self.tracer.enabled:
-            self.tracer.span("retire", "retire", t0, time.perf_counter(),
-                             self.clock, {"rows": len(inf.retires)})
+        with self.tracer.phase("retire", "retire") as ph:
+            self._retire(inf)
+            if self.tracer.enabled:
+                ph.args = {"rows": len(inf.retires)}
 
     # ------------------------------------------------------------------
     def _retire(self, inf: Optional[_InflightStep]) -> None:
@@ -1126,25 +1153,29 @@ class Engine:
         state-snapshot claim needs returning."""
         if inf is None:
             return
-        t0 = time.perf_counter()
-        sampled = self.runner.fetch_sampled(inf.handle)
-        self.clock += (time.perf_counter() - t0) * self.ecfg.time_scale
-        for r, epoch, row, patch_idx, bpos, slot in inf.retires:
-            if r.epoch != epoch:
-                if slot is not None:
-                    self.st_mgr.release(slot)
-                continue
-            # first-token arrival defines decode start: the clock above
-            # just absorbed this step's device time, so TTFT/prefill
-            # keep including the prefill step's execution (stamping at
-            # submit would shift it into the decode stage)
-            if r.state == State.DECODE and r.t_decode_start is None:
-                r.t_decode_start = self.clock
-            if patch_idx is not None:
-                r.output_tokens[patch_idx] = int(sampled[row])
-            if bpos is not None:
-                self._register_decode_block(r, bpos, slot)
-        self._finish_requests()
+        tr = self.tracer
+        with tr.phase("retire", "fetch"):
+            t0 = time.perf_counter()
+            sampled = self.runner.fetch_sampled(inf.handle)
+            self.clock += (time.perf_counter() - t0) * self.ecfg.time_scale
+        with tr.phase("retire", "finish"):
+            for r, epoch, row, patch_idx, bpos, slot in inf.retires:
+                if r.epoch != epoch:
+                    if slot is not None:
+                        self.st_mgr.release(slot)
+                    continue
+                # first-token arrival defines decode start: the clock
+                # above just absorbed this step's device time, so
+                # TTFT/prefill keep including the prefill step's
+                # execution (stamping at submit would shift it into the
+                # decode stage)
+                if r.state == State.DECODE and r.t_decode_start is None:
+                    r.t_decode_start = self.clock
+                if patch_idx is not None:
+                    r.output_tokens[patch_idx] = int(sampled[row])
+                if bpos is not None:
+                    self._register_decode_block(r, bpos, slot)
+            self._finish_requests()
 
     # ------------------------------------------------------------------
     def _adopt_canonical(self, r: Request, b: int, h) -> None:

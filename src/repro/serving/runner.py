@@ -75,6 +75,12 @@ from repro.obs.tracer import Tracer
 
 NEG_INF = -1e30
 
+# the named scopes of the mixed step (``_mixed_impl``), as its ops' paths
+# carry them in the compiled module's metadata and the device trace;
+# ``lora`` is ``lora_delta_dispatch``'s own, nested in ``qkv`` or ``ssd``
+STEP_SCOPES = ("embed", "qkv", "lora", "kv_write", "attention", "out_proj",
+               "mlp", "logits", "ssd")
+
 
 def next_pow2(n: int, lo: int = 1) -> int:
     v = lo
@@ -106,17 +112,15 @@ def log_d2h(log: List[Tuple[int, str, str]], elems: int, dtype: str,
     Overflow trims in bulk, keeping the most recent ``D2H_LOG_KEEP``
     entries in order (unit-tested in ``tests/test_analysis.py``).
 
-    ``tracer`` (the runner's, when tracing is on) mirrors the transfer
-    into the unified trace: a "d2h" event on the retire track plus
-    per-tag element/transfer counters — the log and the trace stay one
-    source of truth for the ids-only-D2H invariant.
+    ``tracer`` (the runner's, when tracing is on) counts the transfer
+    per tag (``d2h_<tag>_transfers_total`` / ``d2h_<tag>_elems_total``,
+    exported to Prometheus); the step fetch's time is the engine's
+    ``fetch`` phase.
     """
     if len(log) >= D2H_LOG_MAX:
         del log[:len(log) - D2H_LOG_KEEP]
     log.append((elems, dtype, tag))
     if tracer is not None and tracer.enabled:
-        tracer.event("retire", "d2h", None,
-                     {"elems": elems, "dtype": dtype, "tag": tag})
         tracer.count(f"d2h_{tag}_transfers_total")
         tracer.count(f"d2h_{tag}_elems_total", elems)
 
@@ -409,12 +413,16 @@ def _mixed_impl(spec: RunnerSpec, params, adapter_layers, k_pool, v_pool,
     ``sampled`` array is ever fetched by the host.
     """
     cfg, rt = spec.cfg, spec.rt
-    # decode rows submitted before their token reached the host read the
-    # previous step's sampled token straight from the device buffer
-    tok_ids = jnp.where(from_buf, tok_buf[tok_slots], tok_ids)
-    tok_emb = params["embed"]["tok"][tok_ids]
-    x = jnp.where(use_embeds[:, None], embeds.astype(tok_emb.dtype),
-                  tok_emb)[None]                             # (1, Tb, d)
+    # each part of the step runs under a named scope (``STEP_SCOPES``),
+    # which names its ops in the device trace and changes nothing else
+    with jax.named_scope("embed"):
+        # decode rows submitted before their token reached the host read
+        # the previous step's sampled token straight from the device
+        # buffer
+        tok_ids = jnp.where(from_buf, tok_buf[tok_slots], tok_ids)
+        tok_emb = params["embed"]["tok"][tok_ids]
+        x = jnp.where(use_embeds[:, None], embeds.astype(tok_emb.dtype),
+                      tok_emb)[None]                         # (1, Tb, d)
     Tb = tok_ids.shape[0]
     pos2 = positions[None]                                   # (1, Tb)
     aidx2 = adapter_idx[None]
@@ -425,36 +433,41 @@ def _mixed_impl(spec: RunnerSpec, params, adapter_layers, k_pool, v_pool,
         lp = layers_params[li]
         al = adapter_layers[li]
         if kind == SSM:
-            h = Lyr.rmsnorm(x, lp["ln"], cfg.norm_eps)
-            y, l_ssm, l_conv, sb_s, sb_c = ssm_lib.ssd_ragged_forward(
-                lp["ssm"], cfg, h[0], live_ssm=live_ssm[si],
-                live_conv=live_conv[si], tok_slots=tok_slots,
-                row_cols=row_cols, seg_ids=req_rows,
-                snap_rows=snap_rows, last_rows=out_rows,
-                row_slots=run_slots, alora=al, adapter_idx=adapter_idx,
-                impl=spec.ssd_impl, lora_impl=spec.lora_impl,
-                active_slots=active_slots)
-            live_ssm = live_ssm.at[si].set(l_ssm)
-            live_conv = live_conv.at[si].set(l_conv)
-            boundary_ssm.append(sb_s)
-            boundary_conv.append(sb_c)
-            x = x + y[None]
+            with jax.named_scope("ssd"):
+                h = Lyr.rmsnorm(x, lp["ln"], cfg.norm_eps)
+                y, l_ssm, l_conv, sb_s, sb_c = ssm_lib.ssd_ragged_forward(
+                    lp["ssm"], cfg, h[0], live_ssm=live_ssm[si],
+                    live_conv=live_conv[si], tok_slots=tok_slots,
+                    row_cols=row_cols, seg_ids=req_rows,
+                    snap_rows=snap_rows, last_rows=out_rows,
+                    row_slots=run_slots, alora=al, adapter_idx=adapter_idx,
+                    impl=spec.ssd_impl, lora_impl=spec.lora_impl,
+                    active_slots=active_slots)
+                live_ssm = live_ssm.at[si].set(l_ssm)
+                live_conv = live_conv.at[si].set(l_conv)
+                boundary_ssm.append(sb_s)
+                boundary_conv.append(sb_c)
+                x = x + y[None]
             si += 1
         else:
-            h = Lyr.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-            q, k, v = Lyr.qkv_project(lp["attn"], cfg, h, al, aidx2,
-                                      lora_impl=spec.lora_impl,
-                                      active_slots=active_slots)
-            q = Lyr.apply_rope(q, pos2, cfg.rope_theta)
-            k = Lyr.apply_rope(k, pos2, cfg.rope_theta)
-            k_pool = k_pool.at[ai, write_bids, write_offs].set(k[0])
-            v_pool = v_pool.at[ai, write_bids, write_offs].set(v[0])
-            o = attn_dispatch.ragged_paged_attention(
-                q[0], k_pool[ai], v_pool[ai], block_tables, req_rows,
-                q_lens, window=spec.window, impl=spec.attn_impl)
-            if spec.shard is not None:
-                o = spec.shard.constrain(o, spec.shard.attn_out)
-            x = x + Lyr.out_project(lp["attn"], cfg, o[None])
+            with jax.named_scope("qkv"):
+                h = Lyr.rmsnorm(x, lp["ln1"], cfg.norm_eps)
+                q, k, v = Lyr.qkv_project(lp["attn"], cfg, h, al, aidx2,
+                                          lora_impl=spec.lora_impl,
+                                          active_slots=active_slots)
+                q = Lyr.apply_rope(q, pos2, cfg.rope_theta)
+                k = Lyr.apply_rope(k, pos2, cfg.rope_theta)
+            with jax.named_scope("kv_write"):
+                k_pool = k_pool.at[ai, write_bids, write_offs].set(k[0])
+                v_pool = v_pool.at[ai, write_bids, write_offs].set(v[0])
+            with jax.named_scope("attention"):
+                o = attn_dispatch.ragged_paged_attention(
+                    q[0], k_pool[ai], v_pool[ai], block_tables, req_rows,
+                    q_lens, window=spec.window, impl=spec.attn_impl)
+                if spec.shard is not None:
+                    o = spec.shard.constrain(o, spec.shard.attn_out)
+            with jax.named_scope("out_proj"):
+                x = x + Lyr.out_project(lp["attn"], cfg, o[None])
             if cfg.is_encoder_decoder:
                 hx = Lyr.rmsnorm(x, lp["xln"], cfg.norm_eps)
                 qx = (hx[0] @ lp["xattn"]["wq"]).reshape(
@@ -462,16 +475,18 @@ def _mixed_impl(spec: RunnerSpec, params, adapter_layers, k_pool, v_pool,
                 ox = packed_cross_attention_ref(
                     qx, xkv[0][ai][req_rows], xkv[1][ai][req_rows])
                 x = x + Lyr.out_project(lp["xattn"], cfg, ox[None])
-            x, _ = M.mlp_sublayer(lp, cfg, rt, x)
+            with jax.named_scope("mlp"):
+                x, _ = M.mlp_sublayer(lp, cfg, rt, x)
             ai += 1
-    x = Lyr.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = M.logits_for(params, cfg, x[0][out_rows])       # (Rb, V)
-    # on-device sampling: argmax per request row; the sampled ids are the
-    # step's only host-visible output AND feed the next step's decode
-    # rows through the per-run-slot token buffer.  Padded request rows
-    # all target the reserved dump slot.
-    sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    tok_buf = tok_buf.at[run_slots].set(sampled)
+    with jax.named_scope("logits"):
+        x = Lyr.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = M.logits_for(params, cfg, x[0][out_rows])   # (Rb, V)
+        # on-device sampling: argmax per request row; the sampled ids are
+        # the step's only host-visible output AND feed the next step's
+        # decode rows through the per-run-slot token buffer.  Padded
+        # request rows all target the reserved dump slot.
+        sampled = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        tok_buf = tok_buf.at[run_slots].set(sampled)
     b_ssm = jnp.stack(boundary_ssm) if boundary_ssm else 0
     b_conv = jnp.stack(boundary_conv) if boundary_conv else 0
     if spec.shard is not None:
@@ -674,7 +689,7 @@ class ModelRunner:
         # logits; see ``log_d2h`` for the tag vocabulary
         self.d2h_fetches: List[Tuple[int, str, str]] = []
         # trace recorder shared with the owning engine (a disabled one
-        # when constructed standalone) — log_d2h mirrors into it
+        # when constructed standalone) — log_d2h counts into it
         self.tracer = tracer if tracer is not None \
             else Tracer(enabled=False)
 
@@ -890,8 +905,13 @@ class ModelRunner:
         outputs below — the pre-step arrays are dead the moment the step
         is dispatched, and XLA reuses their buffers for the outputs.
         """
-        R = len(mb.block_tables)
-        args = self._assemble_mixed(mb)
+        return self.dispatch_mixed(self._assemble_mixed(mb),
+                                   len(mb.block_tables))
+
+    def dispatch_mixed(self, args: Tuple, n_requests: int) -> StepHandle:
+        """The device half of :meth:`submit_batch`: dispatch
+        ``_mixed_impl`` on the tuple :meth:`_assemble_mixed` returned for
+        a batch of ``n_requests`` rows, and rebind the donated pools."""
         self.call_counts["mixed_step"] += 1
         (self.k_pool, self.v_pool, live_ssm, live_conv, self.tok_buf,
          b_ssm, b_conv, sampled) = _mixed_impl(*args)
@@ -900,7 +920,7 @@ class ModelRunner:
             self.live_ssm, self.live_conv = live_ssm, live_conv
             boundary = (b_ssm, b_conv)
         return StepHandle(sampled=sampled, boundary=boundary,
-                          n_requests=R)
+                          n_requests=n_requests)
 
     def lower_mixed(self, mb: MixedBatch):
         """Lower (but do not execute) the mixed step EXACTLY as

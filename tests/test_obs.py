@@ -3,6 +3,11 @@
 Covers the contracts ``docs/observability.md`` promises:
 
 * the Tracer's bounded rings trim in bulk and count what they dropped;
+* engine phases nest as the work nests, each opening one profiler
+  annotation ``engine.<phase>``; a disabled tracer never calls the
+  annotation factory;
+* the mixed step's named scopes reach the compiled module's metadata
+  and change nothing else in it;
 * ``REPRO_TRACE=0`` and ``EngineConfig.trace`` kill recording entirely
   (no events, no ledger, no counters — the hot path stays untouched);
 * the Perfetto export is a deterministic function of the ring contents
@@ -15,7 +20,9 @@ Covers the contracts ``docs/observability.md`` promises:
   hit counters on attention-only archs (the paper's central quantity
   is accounted, not sampled).
 """
+import contextlib
 import json
+import re
 
 import jax
 import numpy as np
@@ -26,10 +33,11 @@ from repro.core.alora import AdapterSpec, init_adapter_weights
 from repro.models import init_params
 from repro.obs import (TRACE_RING_KEEP, TRACE_RING_MAX, Tracer,
                        d2h_summary, prometheus_text, reuse_by_adapter,
-                       to_perfetto, trace_records)
-from repro.obs.tracer import trace_enabled_default
+                       to_perfetto)
+from repro.obs.tracer import NO_PHASE, trace_enabled_default
 from repro.serving import Engine, EngineConfig
 from repro.serving.router import Router
+from repro.serving.runner import STEP_SCOPES
 
 KEY = jax.random.key(0)
 INV = (7, 8, 9)
@@ -92,10 +100,13 @@ def test_ring_overflow_trims_in_bulk_and_counts_dropped():
     # appends resume — never a per-append pop
     assert len(tr.events) == TRACE_RING_KEEP + extra
     assert tr.dropped == TRACE_RING_MAX - TRACE_RING_KEEP
-    # the dropped count is surfaced by the flat exporter
-    recs = trace_records([tr])
-    assert {"kind": "dropped", "value": tr.dropped,
-            "replica": 0} in recs
+    # the most recent records survive, in order
+    assert tr.events[-1] == ("span", "schedule", "s", 0.0, 1.0, None, None)
+    # the dropped count is surfaced by the Perfetto export
+    names = [e["args"]["name"] for e in to_perfetto([tr])["traceEvents"]
+             if e.get("name") == "process_name"]
+    assert names[0] == (f"replica 0 · step phases · {tr.dropped} oldest "
+                        "records dropped")
 
 
 def test_env_kill_switch(monkeypatch):
@@ -245,7 +256,7 @@ def test_fleet_trace_structure(zoo):
         for phase in ("schedule", "submit", "retire"):
             assert ("span", phase, phase) in names, phase
         spans = [e for e in tr.events
-                 if e[0] == "span" and e[1] == "schedule"]
+                 if e[0] == "span" and e[2] == "schedule"]
         assert len(spans) == tr.counters["steps_total"]
         # lifecycle: one arrival event + one finish summary per request
         arrivals = [e for e in tr.events if e[2] == "arrival"]
@@ -255,7 +266,7 @@ def test_fleet_trace_structure(zoo):
         # schema: every record is a 7-tuple on a known track
         for e in tr.events:
             assert len(e) == 7
-            assert e[1] in ("schedule", "submit", "retire", "pool",
+            assert e[1] in ("step", "schedule", "submit", "retire", "pool",
                             "router", "lifecycle")
     # both replicas actually served work (affinity spread the sessions)
     assert all(e.tracer.counters.get("steps_total", 0) > 0
@@ -280,10 +291,10 @@ def test_fleet_trace_structure(zoo):
              if e.get("ph") == "X" and e["pid"] in (1, 2)]
     assert {e["name"] for e in phase} >= {"schedule", "submit", "retire"}
 
-    # flat records cover every ring; prometheus text parses per family
-    recs = trace_records(tracers)
-    assert sum(1 for r in recs if r.get("kind") == "ledger") == \
-        sum(len(e.tracer.ledger) for e in router.replicas)
+    # the ledger rows are the replicas' admissions; prometheus text
+    # parses per family
+    assert sum(len(e.tracer.ledger) for e in router.replicas) == \
+        sum(e.tracer.counters["admissions_total"] for e in router.replicas)
     text = prometheus_text(tracers)
     for line in text.splitlines():
         assert line.startswith("# TYPE repro_") or \
@@ -299,15 +310,161 @@ def test_fleet_trace_structure(zoo):
 
 def test_async_engine_trace_has_overlapping_phases(zoo):
     """Async submission: the submit span of step N and the retire span
-    of step N's previous in-flight work both exist; d2h retire events
-    carry the int32 step tag (the ids-only invariant, visible in the
-    trace)."""
+    of step N's previous in-flight work both exist; each retire holds
+    one ``fetch`` span, the step's one sync, and the runner logged each
+    fetch as int32 ids (the ids-only invariant)."""
     cfg, _, _ = zoo
     eng = mk_engine(zoo, max_running=8, max_batched_tokens=128)
     run_multiturn(eng, cfg, sessions=3, turns=1)
-    d2h = [e for e in eng.tracer.events if e[2] == "d2h"]
-    step_fetches = [e for e in d2h if (e[6] or {}).get("tag") == "step"]
-    assert step_fetches
-    assert all(e[6]["dtype"] == "int32" for e in step_fetches)
+    spans = [e for e in eng.tracer.events if e[0] == "span"]
+    fetches = [e for e in spans if e[1:3] == ("retire", "fetch")]
+    retires = [e for e in spans if e[1:3] == ("retire", "retire")]
+    assert fetches and len(fetches) == len(retires)
+    assert all(r[3] <= f[3] and f[4] <= r[4]
+               for f, r in zip(fetches, retires))
+    step_fetches = [f for f in eng.runner.d2h_fetches if f[2] == "step"]
+    assert all(dtype == "int32" for _, dtype, _ in step_fetches)
     assert eng.tracer.counters["d2h_step_transfers_total"] == \
-        len(step_fetches)
+        len(step_fetches) == len(fetches)
+    assert eng.async_overlap_steps > 0
+
+
+# ---------------------------------------------------------------------------
+# engine phases: profiler annotations that nest as the work nests
+# ---------------------------------------------------------------------------
+class FakeAnnotations:
+    """An annotation factory that logs each annotation's name with the
+    name of the one open around it (its parent)."""
+
+    def __init__(self):
+        self.open, self.log = [], []
+
+    def __call__(self, name):
+        return self._ctx(name)
+
+    @contextlib.contextmanager
+    def _ctx(self, name):
+        self.log.append((name, self.open[-1] if self.open else None))
+        self.open.append(name)
+        try:
+            yield
+        finally:
+            self.open.pop()
+
+
+PARENT = {"engine.prefetch": "engine.step", "engine.schedule": "engine.step",
+          "engine.admit": "engine.schedule", "engine.submit": "engine.step",
+          "engine.assemble": "engine.submit",
+          "engine.dispatch": "engine.submit", "engine.retire": "engine.step",
+          "engine.fetch": "engine.retire", "engine.finish": "engine.retire",
+          "engine.step": None}
+
+
+def test_phases_nest_as_the_work_nests(zoo):
+    cfg, _, _ = zoo
+    eng = mk_engine(zoo, trace=True)
+    ann = FakeAnnotations()
+    eng.tracer.annotate = ann
+    run_multiturn(eng, cfg, sessions=2, turns=2)
+    assert not ann.open
+    names = {n for n, _ in ann.log}
+    assert names == set(PARENT)
+    for name, parent in ann.log:
+        assert parent == PARENT[name], (name, parent)
+    # the ring holds the same phases, one span per annotation, each
+    # inside its parent's span and stamped with the virtual clock
+    spans = [e for e in eng.tracer.events if e[0] == "span"]
+    assert len(spans) == len(ann.log)
+    steps = [e for e in spans if e[2] == "step"]
+    for e in spans:
+        assert e[5] is not None
+        parent = PARENT["engine." + e[2]]
+        if parent is None:
+            continue
+        assert any(p[3] <= e[3] and e[4] <= p[4] for p in spans
+                   if p[2] == parent[len("engine."):]), e
+    assert len(steps) == eng.tracer.counters["steps_total"]
+    # the engine's own factory is the profiler's
+    assert mk_engine(zoo).tracer.annotate is jax.profiler.TraceAnnotation
+
+
+def test_phases_off_call_no_factory_and_record_nothing(zoo):
+    cfg, _, _ = zoo
+    eng = mk_engine(zoo, trace=False)
+    ann = FakeAnnotations()
+    eng.tracer.annotate = ann
+    run_multiturn(eng, cfg, sessions=2, turns=1)
+    assert ann.log == []
+    assert not eng.tracer.events and not eng.tracer.counters
+    assert eng.tracer.phase("step", "step") is NO_PHASE
+    with eng.tracer.phase("step", "step") as ph:
+        assert ph is NO_PHASE
+
+
+# ---------------------------------------------------------------------------
+# named scopes in the mixed step
+# ---------------------------------------------------------------------------
+def mixed_step_lowering(zoo, scopes=True, monkeypatch=None):
+    """The mixed step of a captured serving batch, lowered fresh (with
+    ``scopes=False``, every ``jax.named_scope`` a no-op)."""
+    from repro.analysis.step_audit import capture_batch
+    eng = mk_engine(zoo, trace=False)
+    mb = capture_batch(eng)
+    if not scopes:
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    return eng.runner.lower_mixed(mb)
+
+
+def test_mixed_step_carries_every_scope(zoo):
+    low = mixed_step_lowering(zoo)
+    text = low.as_text(debug_info=True)
+    hlo = low.compile().as_text()
+    for scope in STEP_SCOPES:
+        if scope == "ssd":            # no SSM layer in this model
+            continue
+        assert f"/{scope}/" in text, scope
+        assert re.search(rf'op_name="[^"]*/{scope}/', hlo), scope
+    assert re.search(r'op_name="[^"]*/qkv/lora/', hlo)
+
+
+def test_compile_cache_keys_carry_the_scopes(monkeypatch):
+    """JAX's cache key strips op metadata, where the scopes live: the
+    program adds the scope vocabulary to every key instead."""
+    from jax._src import cache_key
+
+    from repro.launch.compile_cache import key_scopes
+    monkeypatch.setattr(cache_key, "custom_hook", cache_key.custom_hook)
+    assert cache_key.custom_hook() == ""
+    tag = key_scopes()
+    assert cache_key.custom_hook() == tag
+    assert all(scope in tag for scope in STEP_SCOPES)
+
+
+def test_engine_is_freed_without_the_cycle_collector(zoo):
+    """The tracer's clock reads the engine through a weak reference, so
+    dropping an engine frees its device pools at once."""
+    import gc
+    import weakref
+    eng = mk_engine(zoo, trace=True)
+    ref = weakref.ref(eng)
+    gc.disable()
+    try:
+        del eng
+        assert ref() is None
+    finally:
+        gc.enable()
+
+
+def test_scopes_change_only_metadata(zoo, monkeypatch):
+    def body(low):
+        return [re.sub(r", metadata=\{[^}]*\}", "", ln)
+                for ln in low.compile().as_text().splitlines()
+                if " = " in ln]
+    scoped = body(mixed_step_lowering(zoo))
+    plain = body(mixed_step_lowering(zoo, False, monkeypatch))
+    # leave no unscoped trace in the caches for later tests
+    monkeypatch.undo()
+    jax.clear_caches()
+    assert scoped == plain
