@@ -69,18 +69,21 @@ class Client:
     """Plays one mix against one engine.
 
     ``adapter_names[i]`` is the engine's name of the mix's adapter ``i``;
-    ``inv`` the invocation tokens every adapter prompt ends in."""
+    ``inv`` the invocation tokens every adapter prompt ends in.  ``clock``
+    and ``sleep`` are the wall clock's unless a test gives its own."""
 
     def __init__(self, eng, mix: Mix, adapter_names: List[str],
                  inv: List[int], vocab: int, *,
                  annotate: bool = False,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter,
+                 sleep: Callable[[float], None] = time.sleep):
         self.eng = eng
         self.mix = mix
         self.names = adapter_names
         self.inv = list(inv)
         self.vocab = vocab
         self.clock = clock
+        self.sleep = sleep
         self.ann = _annotate(annotate)
         self.heap: list = []
         self._seq = itertools.count()
@@ -124,6 +127,15 @@ class Client:
         g = rng_for(self.mix.seed, 8, s.sid, k)
         return list(map(int, g.integers(TOKEN_LO, self.vocab, n)))
 
+    def _message(self, s: Session, k: int) -> List[int]:
+        """Turn ``k``'s new tokens: its shared document, if any, then the
+        session's own."""
+        t = s.turns[k]
+        if t.doc is None:
+            return self._tokens(s, k, t.message)
+        doc = self.mix.document(t.doc, self.vocab)
+        return doc + self._tokens(s, k, t.message - len(doc))
+
     def _submit(self, kind, prompt, max_new, adapter, due, turn) -> Rec:
         now = self.clock()
         name = None if adapter is None else self.names[adapter]
@@ -143,7 +155,7 @@ class Client:
         if due >= self.stop_at:
             return
         t = s.turns[k]
-        prompt = (ctx or []) + self._tokens(s, k, t.message)
+        prompt = (ctx or []) + self._message(s, k)
         tr = TurnRec(due, self._in_window(due), prompt, s, k)
         self.turns.append(tr)
         if t.direct is not None:
@@ -238,7 +250,7 @@ class Client:
                 continue
             nxt = self.heap[0][0] if self.heap else until
             with self.ann("bench.wait"):
-                time.sleep(max(0.0, min(nxt, until) - self.clock()) * 0.5
+                self.sleep(max(0.0, min(nxt, until) - self.clock()) * 0.5
                            if nxt - now > 2e-3 else 0.0)
 
     def measured_done(self) -> bool:

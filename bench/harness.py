@@ -4,8 +4,9 @@ what the window produced against the plain reference.
 
 Everything a cell needs is found by name from ``BENCHMARK.json``:
 ``bench/configs/<config>.json`` (sizes, KV pool, adapters, reference,
-check limit), ``bench/traffic/<mix>.json`` (the generator's parameters)
-and ``bench/metrics/<metric>.py`` (one reader per metric).
+check limit), ``bench/models/<model_type>.py`` (its keys mapped to the
+program's model settings), ``bench/traffic/<mix>.json`` (the generator's
+parameters) and ``bench/metrics/<metric>.py`` (one reader per metric).
 """
 from __future__ import annotations
 
@@ -100,35 +101,22 @@ def load_reference(name: str, bench: Path = BENCH):
 # ---------------------------------------------------------------------------
 # the system under test
 # ---------------------------------------------------------------------------
-ACTIVATION = {"silu": "swiglu"}
+def model_module(conf: dict, bench: Path = BENCH):
+    """``bench/models/<model_type>.py``: the mapping from the
+    configuration's published keys to the program's model settings."""
+    return _module("models", conf["model_type"], bench)
 
 
-def model_fields(conf: dict) -> dict:
+def model_fields(conf: dict, bench: Path = BENCH) -> dict:
     """The program's model settings from a configuration file's keys
     (the published names, at the values the cell runs)."""
-    hf = conf
-    d, H = hf["hidden_size"], hf["num_attention_heads"]
-    return {
-        "name": conf["name"],
-        "num_layers": hf["num_hidden_layers"],
-        "d_model": d,
-        "num_heads": H,
-        "num_kv_heads": hf["num_key_value_heads"],
-        "head_dim": hf.get("head_dim", d // H),
-        "d_ff": hf["intermediate_size"],
-        "vocab_size": hf["vocab_size"],
-        "activation": ACTIVATION[hf["hidden_act"]],
-        "tie_embeddings": hf["tie_word_embeddings"],
-        "rope_theta": hf["rope_theta"],
-        "sliding_window": hf.get("sliding_window") or 0,
-        "norm_eps": hf.get("rms_norm_eps", hf.get("norm_epsilon", 1e-5)),
-        "dtype": hf["torch_dtype"],
-    }
+    return model_module(conf, bench).fields(conf)
 
 
-def model_config(conf: dict):
+def model_config(conf: dict, bench: Path = BENCH):
     from repro.configs.base import ModelConfig
-    return ModelConfig(arch_type="dense", **model_fields(conf))
+    mod = model_module(conf, bench)
+    return ModelConfig(arch_type=mod.ARCH_TYPE, **mod.fields(conf))
 
 
 class CompileLog:
@@ -179,19 +167,21 @@ def build_system(cell: Cell, seed: int, trace: bool,
 
     from bench import weights
     conf = cell.config
-    cfg = model_config(conf)
+    mod = model_module(conf, cell.bench)
+    cfg = model_config(conf, cell.bench)
     serving = conf["serving"]
     rank, n_ad = serving["adapter_rank"], serving["adapters"]
     shapes = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.key(0))
-    params = weights.make_params(shapes, seed, conf["published"][
-        "num_hidden_layers"], conf.get("init_std", 0.02))
+    params = weights.make_params(shapes, seed, weights.init_rule(
+        mod, conf["published"]["num_hidden_layers"],
+        conf.get("init_std", 0.02)))
     ashapes = jax.eval_shape(lambda k: init_adapter_weights(k, cfg, rank),
                              jax.random.key(0))
     ads = [weights.make_adapter(ashapes, seed, i, cfg.d_model, rank,
                                 serving["adapter_gain"])
            for i in range(n_ad)]
     jax.block_until_ready((params, ads))
-    sysm = System(cfg, model_fields(conf), params, ads,
+    sysm = System(cfg, mod.fields(conf), params, ads,
                   [f"intrinsic{i}" for i in range(n_ad)],
                   list(serving["invocation_tokens"]), rank)
     if engine:
@@ -334,12 +324,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
              warm: bool = True, control: bool = False,
              system: Optional[System] = None, do_check: bool = True,
              log=lambda s: print(s, file=sys.stderr, flush=True),
-             fault: Optional[Callable] = None) -> dict:
+             fault: Optional[Callable] = None,
+             clock: Callable[[], float] = time.perf_counter,
+             sleep: Callable[[float], None] = time.sleep) -> dict:
     """One run of one cell; returns the result object (without device).
 
     ``system`` reuses weights already made (a fresh engine serves them);
     ``fault`` (tests only) is called with the engine before the window,
-    to break the timed path underneath."""
+    to break the timed path underneath.  ``clock`` and ``sleep`` (tests
+    only) replace the wall clock that times the traffic, the window and
+    the drain; ``setup_s`` stays on the wall clock."""
     import jax
     mix_p = cell.mix
     compiles = CompileLog()
@@ -356,7 +350,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     sessions = mix.planned(warmup_s + seconds, n_closed=4096)
     lo, hi = traffic.context_bounds(sessions, len(sysm.inv))
     client = driver.Client(eng, mix, sysm.names, sysm.inv,
-                           sysm.cfg.vocab_size, annotate=trace)
+                           sysm.cfg.vocab_size, annotate=trace,
+                           clock=clock, sleep=sleep)
     if warm:
         t = time.perf_counter()
         keys = step_keys(eng, lo, hi, mix_p.get("adapters", 0))
@@ -373,7 +368,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
             f"compiles so far")
     if fault is not None:
         fault(eng)
-    t_start = time.perf_counter()
+    t_start = clock()
     client.start(t_start, sessions)
     trace_s = mix_p.get("trace_s", 4.0) if trace else 0.0
     t0 = t_start + warmup_s
@@ -395,12 +390,12 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
         opts.python_tracer_level = 0        # host: the annotations only
         opts.host_tracer_level = 1
         jax.profiler.start_trace(str(OUT / "trace"), profiler_options=opts)
-        p0 = time.perf_counter()
+        p0 = clock()
         client.run(until=p0 + trace_s)
         jax.profiler.stop_trace()
-        profile = (p0, time.perf_counter())
+        profile = (p0, clock())
     client.run(until=t1 + drain_cap, measured_only=True)
-    end = time.perf_counter()
+    end = clock()
     in_window = compiles.n - n0
     log(f"window {seconds:.0f} s from {setup_s:.1f} s after start; drained "
         f"{end - t1:.1f} s; {in_window} backend compiles in the window "

@@ -2,7 +2,9 @@
 TPU, and on the CPU drives a whole run of a small cell (the fixture under
 ``data/fixture``, granite-3.2-8b's reduced widths in bf16) through the
 engine: sound, the check passes; with the timed path broken underneath,
-it fails; and the fp8 control and the adapter faults fail it too."""
+it fails; and the fp8 control and the adapter faults fail it too.  An
+architecture the benchmark has no files for (the fixture's windowed GELU
+decoder) runs through the same harness from the fixture's files alone."""
 from __future__ import annotations
 
 import dataclasses
@@ -24,9 +26,9 @@ sys.path.insert(0, str(ROOT))
 from bench import harness  # noqa: E402
 
 
-def fixture_cell():
+def fixture_cell(name="tiny.turns"):
     spec = harness.load_spec(FIXTURE)
-    return harness.load_cell(spec, "tiny.turns", FIXTURE)
+    return harness.load_cell(spec, name, FIXTURE)
 
 
 def test_finds_config_mix_and_metric_by_name():
@@ -81,11 +83,31 @@ def test_exits_nonzero_with_only_the_benchmark_files(tmp_path):
 # ---------------------------------------------------------------------------
 # whole runs on the CPU
 # ---------------------------------------------------------------------------
-def run_fixture(seed=3, fault=None, control=False):
-    t = time.perf_counter()
-    return harness.run_cell(fixture_cell(), seed, 1.5, False, t_process=t,
-                            warm=False, control=control, fault=fault,
-                            log=lambda s: None)
+class StepClock:
+    """The client's clock in these runs: each read moves it on by
+    ``tick`` seconds and a sleep by what was asked.  Which requests fall
+    in the window, and so which ones the check samples, then follows from
+    the client's own calls and not from how fast the host runs the engine
+    (on the wall clock a slow host measured fewer turns, and the sample
+    could miss every adapter token that the fault changes)."""
+
+    def __init__(self, tick: float = 0.01):
+        self.t, self.tick = 0.0, tick
+
+    def __call__(self) -> float:
+        self.t += self.tick
+        return self.t
+
+    def sleep(self, s: float) -> None:
+        self.t += s
+
+
+def run_fixture(seed=3, fault=None, control=False, cell="tiny.turns"):
+    t, clock = time.perf_counter(), StepClock()
+    return harness.run_cell(fixture_cell(cell), seed, 1.5, False,
+                            t_process=t, warm=False, control=control,
+                            fault=fault, log=lambda s: None, clock=clock,
+                            sleep=clock.sleep)
 
 
 def test_a_sound_run_is_correct_and_its_control_is_not():
@@ -167,3 +189,58 @@ def test_the_sample_holds_every_kind_of_request():
                              + r.max_new)
         assert sorted(r.kind for r in out) == ["base", "direct", "eval"]
         assert all(r.done is not None for r in out)
+
+
+# ---------------------------------------------------------------------------
+# a new architecture, from new files alone
+# ---------------------------------------------------------------------------
+def test_a_new_architecture_is_mapped_by_its_own_model_module():
+    cell = fixture_cell("tiny-gelu.turns")
+    mod = harness.model_module(cell.config, cell.bench)
+    assert mod.__file__ == str(FIXTURE / "bench" / "models"
+                               / "windowed_gelu.py")
+    cfg = harness.model_config(cell.config, cell.bench)
+    assert (cfg.arch_type, cfg.activation, cfg.sliding_window) \
+        == ("dense", "gelu", 64)
+    granite = harness.model_module({"model_type": "granite"})
+    with pytest.raises(KeyError):      # granite's mapping knows no GELU
+        granite.fields(cell.config)
+
+
+def test_a_new_architecture_runs_correct():
+    """GELU-tanh MLP and a 64-token window over 136-256-token contexts,
+    checked against the fixture's own reference."""
+    out = run_fixture(cell="tiny-gelu.turns")
+    assert (out["run"].model["activation"],
+            out["run"].model["sliding_window"]) == ("gelu", 64)
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert out["check"]["tokens"]["value"] >= 32
+    assert harness.is_correct(out["check"])
+
+
+def test_a_new_architecture_without_its_adapters_is_not_correct():
+    out = run_fixture(cell="tiny-gelu.turns", fault=drop_adapters)
+    assert out["check"]["tokens"]["value"] > 0
+    assert not harness.is_correct(out["check"])
+
+
+def test_a_model_module_names_the_norm_scales():
+    """A block whose norm scales carry other names gets 1 + N(0, 0.1)
+    there once its module lists them, without an edit to weights.py."""
+    import types
+
+    import jax
+    import jax.numpy as jnp
+
+    from bench import weights
+    shapes = {"blk": {"scale": jax.ShapeDtypeStruct((4096,), jnp.float32),
+                      "w_out": jax.ShapeDtypeStruct((64, 64), jnp.float32)}}
+    plain = weights.make_params(shapes, 5, weights.init_rule(
+        types.SimpleNamespace(), 8))
+    named = weights.make_params(shapes, 5, weights.init_rule(
+        types.SimpleNamespace(NORMS=("scale",), OUT_PROJ=("w_out",)), 8))
+    assert abs(float(plain["blk"]["scale"].mean())) < 0.01
+    assert abs(float(named["blk"]["scale"].mean()) - 1.0) < 0.01
+    assert float(named["blk"]["scale"].std()) == pytest.approx(0.1, rel=0.1)
+    assert float(named["blk"]["w_out"].std()) == pytest.approx(
+        0.02 / 4.0, rel=0.1)

@@ -4,6 +4,7 @@ are played as a base answer followed by evaluations over history and
 answer."""
 from __future__ import annotations
 
+import dataclasses
 import enum
 import json
 import sys
@@ -184,3 +185,115 @@ def test_turns_are_base_then_evaluations_over_history_and_answer():
                 assert e.req.prompt == base.req.prompt + answer + [3, 4, 5]
                 assert e.due >= base.done
             history = base.req.prompt + answer
+
+
+# ---------------------------------------------------------------------------
+# shared documents (the fixture's CPU-sized ``documents`` mix)
+# ---------------------------------------------------------------------------
+DOCS = BENCH / "tests" / "data" / "fixture" / "bench" / "traffic" \
+    / "documents.json"
+VOCAB = 512
+
+
+def doc_mix(seed, drop_documents=False):
+    params = traffic.load_mix(DOCS)
+    if drop_documents:
+        del params["documents"]
+    return traffic.Mix.build(params, seed, 3)
+
+
+def doc_client(m):
+    return driver.Client(None, m, [f"a{i}" for i in range(4)], [3, 4, 5],
+                         VOCAB)
+
+
+@pytest.mark.parametrize("seed", [1, BIG])
+def test_every_first_message_starts_with_a_document(seed):
+    m = doc_mix(seed)
+    count = m.params["documents"]["count"]
+    assert len(m.doc_lens) == count
+    c = doc_client(m)
+    for s in m.planned(0, n_closed=300):
+        first, *later = s.turns
+        assert 0 <= first.doc < count
+        doc = m.document(first.doc, VOCAB)
+        assert len(doc) == m.doc_lens[first.doc]
+        msg = c._message(s, 0)
+        assert len(msg) == first.message
+        assert msg[:len(doc)] == doc
+        assert all(t.doc is None for t in later)
+
+
+def test_a_document_has_the_same_ids_in_every_session_of_a_seed():
+    m = doc_mix(7)
+    c = doc_client(m)
+    seen = {}
+    for s in m.planned(0, n_closed=300):
+        d = s.turns[0].doc
+        seen.setdefault(d, set()).add(
+            tuple(c._message(s, 0)[:m.doc_lens[d]]))
+    assert sorted(seen) == list(range(8))
+    assert all(len(ids) == 1 for ids in seen.values())
+    assert len({ids.pop() for ids in seen.values()}) == 8
+    # another seed, other ids; the same lengths
+    other = doc_mix(8)
+    assert other.doc_lens == m.doc_lens
+    assert other.document(0, VOCAB) != m.document(0, VOCAB)
+
+
+def test_document_shares_follow_zipf():
+    """The picks are a pool of 64, apportioned to Zipf(1.0) by largest
+    remainder (each share within 1/64 of Zipf's), drawn cycle after cycle:
+    2,000 sessions are 31 whole cycles and 16 picks of the next, which move
+    a share by at most 16/2000.  Hence the tolerance."""
+    n = 2000
+    m = doc_mix(BIG)
+    counts = Counter(s.turns[0].doc for s in m.planned(0, n_closed=n))
+    w = [1.0 / (i + 1) for i in range(8)]
+    for i in range(8):
+        assert abs(counts[i] / n - w[i] / sum(w)) <= 1 / 64 + 16 / n, i
+    # one cycle: 64 * (1 / (i + 1)) / H_8 rounded by largest remainder
+    cycle = Counter(traffic.zipf_picks(8, 1.0, 64))
+    assert [cycle[i] for i in range(8)] == [23, 12, 8, 6, 5, 4, 3, 3]
+
+
+@pytest.mark.parametrize("seed", [1, 2, BIG])
+def test_length_support_holds_every_document_prompt(seed):
+    sup = set(traffic.length_support(doc_mix(1)))
+    m = doc_mix(seed)
+    sessions = m.planned(0, n_closed=500)
+    assert set(traffic.prompt_lengths(sessions, 3)) <= sup
+    lo, hi = traffic.context_bounds(sessions, 3)
+    assert lo >= min(m.doc_lens) + m.params["first_message"]["lo"]
+    assert hi <= m.params["max_context"]
+    # what the client really submits, played against the stand-in engine
+    eng = FakeEngine()
+    t = [0.0]
+    c = driver.Client(eng, m, [f"a{i}" for i in range(4)], [3, 4, 5], VOCAB,
+                      clock=lambda: t[0])
+    c.window, c.stop_at = (0.0, 1e9), 1e9
+    c.start(0.0, sessions[:40])
+    for _ in range(3000):
+        t[0] += 1e-3
+        c._release_due(t[0])
+        if c.busy():
+            c.step()
+    assert len([tr for tr in c.turns if tr.done is not None]) \
+        == sum(len(s.turns) for s in sessions[:40])
+    assert {len(r.prompt) for r in eng.log} <= sup
+
+
+def test_without_documents_the_plan_is_the_rest_of_the_same():
+    """The document pools draw on streams of their own: the same mix
+    without them plans the same sessions, each first message shorter by
+    its document, with the same tokens after it."""
+    with_docs, plain = doc_mix(BIG), doc_mix(BIG, drop_documents=True)
+    a, b = with_docs.planned(0, n_closed=200), plain.planned(0, n_closed=200)
+    ca, cb = doc_client(with_docs), doc_client(plain)
+    for s, p in zip(a, b):
+        n = with_docs.doc_lens[s.turns[0].doc]
+        assert s.turns[0].message - n == p.turns[0].message
+        assert [dataclasses.replace(t, doc=None) for t in s.turns[1:]] \
+            == p.turns[1:]
+        assert ca._message(s, 0)[n:] == cb._message(p, 0)
+        assert (s.sid, s.arrival, s.think) == (p.sid, p.arrival, p.think)

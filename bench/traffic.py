@@ -10,6 +10,14 @@ one adapter (``direct_adapter_share``): its prompt ends in the
 invocation tokens and nothing follows.  The next turn is due a think
 time after the turn's last token.
 
+A mix may share documents among its sessions (``documents``: ``count``
+documents, their lengths ``len``, popularity Zipf(``zipf``)): a session's
+first message is then one document followed by the ``first_message``
+tokens.  Document ``d`` has the ``d``-th of ``count`` quantiles of
+``len`` (of its ``values``, in their order) for every seed, and the same
+token ids in every session of a seed, so the prefix cache can serve it;
+``d = 0`` is the most popular.
+
 Sessions arrive open loop (``arrival.kind = "poisson"``, a rate per
 second) or come from a fixed number of closed-loop clients, each
 starting its next session when the last one has ended.
@@ -83,6 +91,19 @@ def quantile_pool(spec: dict, n: int, quantum: int = 1) -> List[float]:
     return out
 
 
+def zipf_picks(count: int, a: float, n: int) -> List[int]:
+    """``n`` picks among ``count`` items, item ``i`` as often as Zipf(``a``)
+    gives it, (i + 1)^-a / sum: the counts apportioned by largest
+    remainder, so each share is within 1 / n of Zipf's."""
+    w = [(i + 1) ** -a for i in range(count)]
+    exact = [n * x / sum(w) for x in w]
+    k = [int(e) for e in exact]
+    for i in sorted(range(count), key=lambda i: k[i] - exact[i])[
+            :n - sum(k)]:
+        k[i] += 1
+    return [i for i in range(count) for _ in range(k[i])]
+
+
 class Pool:
     """Draws a fixed multiset in a seeded order, reshuffled each cycle."""
 
@@ -106,6 +127,8 @@ class Turn:
     answer: int                      # tokens the base (or adapter) answers
     evals: List[Tuple[int, int]]     # (adapter index, tokens) per evaluation
     direct: Optional[int] = None     # adapter index of a direct turn
+    doc: Optional[int] = None        # the shared document the message
+                                     # starts with (counted in ``message``)
 
 
 @dataclass
@@ -123,6 +146,8 @@ class Mix:
     seed: int
     n_inv: int                       # invocation tokens per adapter prompt
     pools: Dict[str, Pool] = field(default_factory=dict)
+    doc_lens: List[int] = field(default_factory=list)
+    _doc_ids: Dict[Tuple[int, int], List[int]] = field(default_factory=dict)
 
     @classmethod
     def build(cls, params: dict, seed: int, n_inv: int) -> "Mix":
@@ -158,7 +183,24 @@ class Mix:
                 quantile_pool({"dist": "exponential",
                                "mean": 1.0 / arr["rate_per_s"]}, n, 0),
                 rng_for(seed, 6))
+        docs = params.get("documents")
+        if docs:
+            # after the pools above, on streams of their own, so that a
+            # mix without documents draws what it always drew
+            m.doc_lens = quantile_pool(docs["len"], docs["count"], q)
+            m.pools["doc"] = Pool(zipf_picks(docs["count"], docs["zipf"], n),
+                                  rng_for(seed, 10))
         return m
+
+    def document(self, d: int, vocab: int) -> List[int]:
+        """Document ``d``'s token ids: the same in every session of the
+        seed."""
+        key = (d, vocab)
+        if key not in self._doc_ids:
+            g = rng_for(self.seed, 11, d)
+            self._doc_ids[key] = list(map(int, g.integers(
+                TOKEN_LO, vocab, self.doc_lens[d])))
+        return self._doc_ids[key]
 
     def sessions(self) -> Iterator[Session]:
         """The seed's sessions in order of arrival (or of start, closed
@@ -177,6 +219,10 @@ class Mix:
             for k in range(n_turns):
                 msg = int(self.pools["first" if k == 0 else "message"]
                           .draw())
+                doc = None
+                if k == 0 and "doc" in self.pools:
+                    doc = int(self.pools["doc"].draw())
+                    msg += self.doc_lens[doc]
                 ans = int(self.pools["answer"].draw())
                 direct = None
                 evals: List[Tuple[int, int]] = []
@@ -198,7 +244,7 @@ class Mix:
                             f"mix: a first turn needs {need} tokens, over "
                             f"max_context {cap}")
                     break
-                turns.append(Turn(msg, ans, evals, direct))
+                turns.append(Turn(msg, ans, evals, direct, doc))
                 think.append(gap)
                 ctx += msg + ans
             yield Session(sid, arrival, turns, think)
@@ -247,6 +293,8 @@ def length_support(mix: "Mix") -> List[int]:
     ctx = {0}
     for k in range(int(max(vals["turns"]))):
         msgs = vals["first" if k == 0 else "message"]
+        if k == 0 and mix.doc_lens:
+            msgs = {d + m for d in mix.doc_lens for m in msgs}
         nxt = set()
         for c in ctx:
             for m in msgs:

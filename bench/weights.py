@@ -5,8 +5,11 @@ The leaves follow the program's parameter layout (their shapes are read
 from ``jax.eval_shape`` of the program's initialiser); their values are
 the benchmark's: projections N(0, std), output projections N(0, std /
 sqrt(2 * layers)) with std 0.02 unless the configuration states
-``init_std``, norms 1 + N(0, 0.1).  Adapters: A ~ N(0, 1/sqrt(d)),
-B ~ N(0, gain * 0.02 / sqrt(rank)).  The reference reads the same trees.
+``init_std``, norms 1 + N(0, 0.1).  Which leaves are norm scales and
+output projections, by the last name on their path, the configuration's
+model module says (``NORMS``, ``OUT_PROJ``; these defaults where it does
+not).  Adapters: A ~ N(0, 1/sqrt(d)), B ~ N(0, gain * 0.02 / sqrt(rank)).
+The reference reads the same trees.
 """
 from __future__ import annotations
 
@@ -39,16 +42,24 @@ def _key(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def make_params(shapes, seed: int, n_layers_published: int,
-                std: float = 0.02):
-    """Model weights shaped like ``shapes`` (a ShapeDtypeStruct tree)."""
+def init_rule(model_mod, n_layers_published: int, std: float = 0.02):
+    """``scale_of(leaf name) -> (mean, std)`` of the model weights, with the
+    leaf names of ``model_mod`` (a ``bench/models`` module)."""
+    norms = getattr(model_mod, "NORMS", NORMS)
+    out_proj = getattr(model_mod, "OUT_PROJ", OUT_PROJ)
     out_std = std / math.sqrt(2 * n_layers_published)
 
     def scale_of(name):
-        if name in NORMS:
+        if name in norms:
             return 1.0, 0.1
-        return 0.0, out_std if name in OUT_PROJ else std
+        return 0.0, out_std if name in out_proj else std
 
+    return scale_of
+
+
+def make_params(shapes, seed: int, scale_of):
+    """Model weights shaped like ``shapes`` (a ShapeDtypeStruct tree),
+    each leaf drawn as ``scale_of`` (``init_rule``) says."""
     return jax.jit(partial(_fill, shapes, scale_of=scale_of))(_key(seed))
 
 
